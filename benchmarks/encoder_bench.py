@@ -104,7 +104,10 @@ def _plan_in_fresh_process(backend: str, n: int, s: int, over: dict,
     out = subprocess.run(
         [sys.executable, "-c", _CHILD, backend, str(n), str(s), cache,
          json.dumps(over)],
-        env=dict(os.environ), capture_output=True, text=True, timeout=600)
+        # host planning only, pinned to the CPU: the child must not
+        # wait on an accelerator this process holds
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=600)
     if out.returncode != 0:
         raise RuntimeError(out.stderr[-2000:])
     return json.loads(out.stdout.strip().splitlines()[-1])
@@ -160,11 +163,11 @@ def run() -> None:
             assert warm["disk_hits"] == 1 and warm["built"] == 0, warm
             tag = backend.replace(":", "_")
             emit(f"encoder/{tag}/plan_cold_process", cold["plan_s"],
-                 f"s={s};fresh interpreter, empty cache")
+                 f"s={s};fresh interpreter, empty cache;platform=cpu")
             emit(f"encoder/{tag}/plan_warm_persistent", warm["plan_s"],
                  f"s={s};speedup={cold['plan_s'] / warm['plan_s']:.1f}x;"
                  f"host half loaded from disk, only device placement "
-                 f"re-ran")
+                 f"re-ran;platform=cpu")
         finally:
             shutil.rmtree(cache, ignore_errors=True)
 

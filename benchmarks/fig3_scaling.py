@@ -48,6 +48,9 @@ def run() -> None:
     base = None
     for ndev in (1, 2, 4, 8):
         env = dict(os.environ)
+        # a CPU device sweep by construction: the child never takes
+        # an accelerator, which this process may already hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = \
             f"--xla_force_host_platform_device_count={ndev}"
         env["PYTHONPATH"] = os.path.join(here, "src")
@@ -64,7 +67,7 @@ def run() -> None:
         emit(f"fig3/devices{ndev}/wall", d["wall_s"],
              f"edges_per_shard={d['edges_per_shard']:.0f};"
              f"work_reduction={base / d['edges_per_shard']:.2f}x;"
-             f"dropped={d['dropped']}")
+             f"dropped={d['dropped']};platform=cpu")
 
 
 if __name__ == "__main__":

@@ -8,10 +8,9 @@ Pallas rows report the RESOLVED compile/interpret mode
 prints a loud warning when a "pallas" row was measured in interpret
 mode — an interpreted kernel timing mistaken for kernel performance is
 exactly the bug the auto-resolved mode exists to surface.  The
-``*_roofline`` rows report achieved-vs-roofline HBM bandwidth from the
-`repro.launch.autotune` traffic models (meaningful on TPU; in
-interpret mode they quantify how far the interpreter is from the
-memory-bound target)."""
+``*_roofline`` rows report achieved HBM bandwidth from the
+`repro.launch.autotune` traffic models, and a roofline share only for
+compiled kernels timed on a TPU (``not_measured`` otherwise)."""
 from __future__ import annotations
 
 import jax
@@ -23,9 +22,8 @@ from repro.graph.edges import make_labels
 from repro.graph.generators import erdos_renyi
 from repro.kernels import ops
 from repro.kernels.gee_scatter import interpret_mode_name, resolve_interpret
-from repro.launch.autotune import (scatter_traffic_bytes,
+from repro.launch.autotune import (roofline_share, scatter_traffic_bytes,
                                    topk_traffic_bytes)
-from repro.launch.roofline import HBM_BW
 
 import numpy as np
 
@@ -48,9 +46,9 @@ def expected_keys() -> list:
 
 def _bw_note(moved: int, seconds: float, mode: str) -> str:
     gbps = moved / seconds / 1e9 if seconds > 0 else 0.0
-    frac = gbps * 1e9 / HBM_BW
-    return (f"achieved={gbps:.3f}GB/s frac={frac * 100:.3f}% "
-            f"of {HBM_BW / 1e9:.0f}GB/s mode={mode}")
+    frac = roofline_share(gbps * 1e9, mode)
+    share = "not_measured" if frac is None else f"{frac * 100:.3f}%"
+    return f"achieved={gbps:.3f}GB/s roofline_share={share} mode={mode}"
 
 
 def run() -> None:
@@ -78,7 +76,7 @@ def run() -> None:
     emit("kernels/gee_pallas/s16000", t, f"mode={mode}")
     d = emb._plan.data
     moved = scatter_traffic_bytes(d["T"], d["rows"].shape[1],
-                                  d["rows"].shape[2], 256, d["kdim"])
+                                  d["rows"].shape[-1], 256, d["kdim"])
     emit("kernels/gee_scatter_roofline/s16000", t,
          _bw_note(moved, t, mode))
 

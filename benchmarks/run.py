@@ -88,6 +88,8 @@ def main() -> None:
                     help="shard count for the serving suite's "
                          "partitioned-engine rows (default 2)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import common
     if args.quick:
         common.QUICK = True

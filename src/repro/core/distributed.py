@@ -40,9 +40,15 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.gee import edge_contributions, make_w
-from repro.models.attention import shard_map
 
 AXIS = "edges"
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    """`jax.shard_map` without the varying-manual-axes check (the
+    bodies mix replicated and per-shard values by hand)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def edge_mesh(devices=None) -> Mesh:

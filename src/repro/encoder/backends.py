@@ -277,7 +277,8 @@ class PallasBackend(Backend):
 
     supports_row_partition = True
     #: v2: partitioned plans pack over local rows [0, hi - lo)
-    plan_version = 2
+    #: v3: packed blocks are (T, BPT, 1, EB), the layout Mosaic tiles
+    plan_version = 3
 
     def plan_host(self, graph, config, w_eff, *, mesh=None):
         from repro.kernels.ops import _round_up, pack_edges

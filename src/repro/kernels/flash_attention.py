@@ -11,16 +11,14 @@ Layout: q (B, H, S, D), k/v (B, KV, S, D), KV | H.  Grid =
 from __future__ import annotations
 
 import functools
+from typing import Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.kernels.gee_scatter import resolve_interpret
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
@@ -70,9 +68,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention(q, k, v, *, bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True):
+                    interpret: Union[bool, str] = "auto"):
     """Causal self-attention. q: (B,H,S,D); k,v: (B,KV,S,D). Returns
-    (B,H,S,D)."""
+    (B,H,S,D).  ``interpret`` resolves per platform like the GEE
+    kernels (`gee_scatter.resolve_interpret`)."""
+    interpret = resolve_interpret(interpret)
     B, H, S, D = q.shape
     KV = k.shape[1]
     G = H // KV
@@ -101,10 +101,10 @@ def flash_attention(q, k, v, *, bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[
-            _VMEM((bq,), jnp.float32),
-            _VMEM((bq,), jnp.float32),
-            _VMEM((bq, D), jnp.float32),
-        ] if _VMEM is not None else [],
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
+        ],
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, H, S, D)

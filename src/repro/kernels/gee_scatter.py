@@ -9,13 +9,24 @@ Z — exactly the op TPUs don't have.  The TPU formulation:
   * grid = (num_tiles, blocks_per_tile); the Z tile (TILE_N, K) stays
     resident in VMEM across the inner grid dimension (revisiting
     BlockSpec), so all accumulation happens on-chip;
-  * each edge block turns its scatter into two one-hot expansions and a
-    dense (TILE_N x EB) @ (EB x K) matmul on the MXU:
-        R[e, r] = [row_local(e) == r]        (EB, TILE_N)
-        C[e, k] = [cls(e) == k] * val(e)     (EB, K)
-        Z_tile += R^T @ C
+  * each edge block is a lane-dense (1, EB) row; the scatter becomes
+    two transposed one-hot expansions and one dense matmul on the MXU:
+        Rt[r, e] = [row_local(e) == r]        (TILE_N, EB)
+        Ct[k, e] = [cls(e) == k] * val(e)     (K, EB)
+        Z_tile += Rt @ Ct^T
     No RMW race is possible: one grid instance owns the tile, and the
     matmul reduction replaces the atomic adds (deterministically).
+
+Packed layout: (T, BPT, 1, EB).  The unit axis makes the block's last
+two dimensions (1, EB) — equal to the array's own and a multiple of
+128 lanes — which is what Mosaic's (8, 128) tiling rule accepts; a
+(T, BPT, EB) array blocked (1, 1, EB) is refused on the chip.  EB must
+be a multiple of 128 for the compiled kernel (any size interprets).
+
+The matmul runs at ``Precision.HIGHEST``: the one-hot is exact in any
+precision but the values (1/class-count times the edge weight) are
+not exact in bf16, and the f32 contract with the numpy oracle is what
+a caller gets from every other backend.
 
 This mirrors how the paper's cache analysis maps to the TPU memory
 hierarchy: their "Z(u,:) stays in processor cache during a vertex's edge
@@ -60,6 +71,27 @@ def interpret_mode_name(interpret: bool) -> str:
     return "interpret" if interpret else "compiled"
 
 
+def edge_block_spec(eb: int) -> pl.BlockSpec:
+    """Block spec of one (1, EB) edge block of a (T, BPT, 1, EB) packed
+    array on the (tile, block) grid — shared by every kernel that walks
+    the destination-tiled layout."""
+    return pl.BlockSpec((None, None, 1, eb), lambda t, b: (t, b, 0, 0))
+
+
+def accumulate_block(rows, cls, val, *, tile_n: int, kdim: int):
+    """One edge block's scatter as a matmul: (1, EB) tile-local rows,
+    classes and values -> the (tile_n, kdim) contribution."""
+    eb = rows.shape[-1]
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_n, eb), 0)
+    cls_iota = jax.lax.broadcasted_iota(jnp.int32, (kdim, eb), 0)
+    Rt = (row_iota == rows).astype(jnp.float32)               # (TILE_N, EB)
+    Ct = (cls_iota == cls).astype(jnp.float32) * val          # (K, EB)
+    return jax.lax.dot_general(
+        Rt, Ct, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                   # (TILE_N, K)
+
+
 def _kernel(rows_ref, cls_ref, val_ref, z_ref, *, tile_n: int, kdim: int):
     b = pl.program_id(1)
 
@@ -67,37 +99,29 @@ def _kernel(rows_ref, cls_ref, val_ref, z_ref, *, tile_n: int, kdim: int):
     def _init():
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    rows = rows_ref[0, 0, :]                                  # (EB,) int32
-    cls = cls_ref[0, 0, :]
-    val = val_ref[0, 0, :].astype(jnp.float32)
-
-    eb = rows.shape[0]
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (eb, tile_n), 1)
-    cls_iota = jax.lax.broadcasted_iota(jnp.int32, (eb, kdim), 1)
-    R = (rows[:, None] == row_iota).astype(jnp.float32)        # (EB, TILE_N)
-    C = (cls[:, None] == cls_iota).astype(jnp.float32) * val[:, None]
-    z_ref[...] += jax.lax.dot_general(
-        R, C, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                    # (TILE_N, K)
+    z_ref[...] += accumulate_block(
+        rows_ref[...], cls_ref[...], val_ref[...].astype(jnp.float32),
+        tile_n=tile_n, kdim=kdim)
 
 
+@functools.partial(jax.jit, static_argnames=("num_tiles", "tile_n",
+                                             "kdim", "interpret"))
 def gee_scatter_pallas(rows, cls, val, *, num_tiles: int, tile_n: int,
                        kdim: int, interpret: Union[bool, str] = "auto"):
-    """rows/cls/val: (T, BPT, EB) packed edge blocks (see ops.pack_edges).
+    """rows/cls/val: (T, BPT, 1, EB) packed edge blocks (see
+    ops.pack_edges).
 
     Returns Z (num_tiles * tile_n, kdim) float32."""
     interpret = resolve_interpret(interpret)
-    T, BPT, EB = rows.shape
+    T, BPT, _, EB = rows.shape
     assert T == num_tiles
-    grid = (T, BPT)
-    eb_spec = pl.BlockSpec((1, 1, EB), lambda t, b: (t, b, 0))
+    eb_spec = edge_block_spec(EB)
     z_spec = pl.BlockSpec((tile_n, kdim), lambda t, b: (t, 0))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, tile_n=tile_n, kdim=kdim),
-        grid=grid,
+        grid=(T, BPT),
         in_specs=[eb_spec, eb_spec, eb_spec],
         out_specs=z_spec,
         out_shape=jax.ShapeDtypeStruct((T * tile_n, kdim), jnp.float32),
         interpret=interpret,
     )(rows, cls, val)
-    return out

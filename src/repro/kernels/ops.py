@@ -3,8 +3,8 @@
 ``gee_pallas`` packs edges into destination-sorted uniform blocks
 (host-side, static shapes) and dispatches the gee_scatter kernel; it is
 the TPU hot path behind ``repro.core.gee`` when running on real
-hardware.  On this CPU container the kernels execute in interpret mode
-(Python evaluation of the kernel body) — correctness-equivalent,
+hardware.  On CPU the kernels execute in interpret mode (Python
+evaluation of the kernel body) — correctness-equivalent,
 performance-irrelevant.
 """
 from __future__ import annotations
@@ -27,8 +27,9 @@ def _round_up(x: int, m: int) -> int:
 def pack_edges(dst, cls, val, n: int, tile_n: int = TILE_N,
                edge_block: int = EDGE_BLOCK):
     """Sort contributions by destination tile and pack into uniform
-    (T, BPT, EB) blocks.  Host-side numpy (static output shapes depend on
-    the max bucket size).  Padded slots: val = 0."""
+    (T, BPT, 1, EB) blocks (the unit axis is the kernels' lane-dense
+    block row, see `gee_scatter`).  Host-side numpy (static output
+    shapes depend on the max bucket size).  Padded slots: val = 0."""
     dst = np.asarray(dst)
     cls = np.asarray(cls)
     val = np.asarray(val)
@@ -50,7 +51,7 @@ def pack_edges(dst, cls, val, n: int, tile_n: int = TILE_N,
     rows_buf[slot] = dst_s - tile_s * tile_n
     cls_buf[slot] = cls_s
     val_buf[slot] = val_s
-    shape = (T, bpt, edge_block)
+    shape = (T, bpt, 1, edge_block)
     return (rows_buf.reshape(shape), cls_buf.reshape(shape),
             val_buf.reshape(shape), T)
 
@@ -73,5 +74,6 @@ def gee_pallas(u, v, w, Y, *, K: int, n: int, tile_n: int = TILE_N,
 
 
 def flash_attention(q, k, v, *, bq: int = fa.DEFAULT_BQ,
-                    bk: int = fa.DEFAULT_BK, interpret: bool = True):
+                    bk: int = fa.DEFAULT_BK,
+                    interpret: Union[bool, str] = "auto"):
     return fa.flash_attention(q, k, v, bq=bq, bk=bk, interpret=interpret)

@@ -4,7 +4,8 @@ The GEE scatter and fused top-k kernels are memory-bound by design
 (the paper's whole point: edge-parallel scatter at memory bandwidth),
 so the right figure of merit for a geometry candidate is **achieved
 HBM fraction**: bytes the kernel must move (from the traffic models
-below) divided by measured wall time, over `roofline.HBM_BW`.
+below) divided by measured wall time, over the chip's peak HBM
+bandwidth (`roofline.peaks` of the device kind).
 
 Search: greedy coordinate descent over the per-kernel geometry space —
 sweep one knob at a time holding the others at the incumbent, repeat
@@ -16,22 +17,21 @@ one command instead of a hand sweep:
     PYTHONPATH=src python -m repro.launch.hillclimb gee-scatter-tune
     PYTHONPATH=src python -m repro.launch.hillclimb gee-topk-tune
 
-On a CPU container the kernels run in interpret mode, so absolute
-times (and hence achieved-bandwidth fractions) are interpreter
-throughput, NOT kernel performance — the tuner prints the resolved
-mode and `benchmarks.kernels_bench` carries the same warning.  The
-machinery itself is platform-independent: on TPU the same commands
-tune the compiled kernels.
+On CPU the kernels run in interpret mode, so absolute times are
+interpreter throughput, NOT kernel performance: the tuner prints the
+resolved mode and reports no roofline share for such timings
+(`benchmarks.kernels_bench` does the same).  On TPU the same commands
+tune the compiled kernels and report their share of the chip's peak.
 """
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import numpy as np
 
-from repro.launch.roofline import HBM_BW
+from repro.launch.roofline import peaks
 
 #: geometry spaces swept by the coordinate descent (ascending so the
 #: sweep output reads as a size scan)
@@ -141,7 +141,7 @@ def tune_scatter(n: int = 20_000, s: int = 200_000, K: int = 16, *,
     e = embs[(best["tile_n"], best["edge_block"])]
     d = e._plan.data
     moved = scatter_traffic_bytes(d["T"], d["rows"].shape[1],
-                                  d["rows"].shape[2], best["tile_n"],
+                                  d["rows"].shape[-1], best["tile_n"],
                                   d["kdim"])
     out.update(_bandwidth(moved, out["seconds"], mode, log=log))
     return out
@@ -181,12 +181,22 @@ def tune_topk(m: int = 50_000, K: int = 16, nq: int = 64,
     return out
 
 
+def roofline_share(bytes_per_s: float, mode: str) -> Optional[float]:
+    """Achieved over peak HBM bandwidth of this device, or None where
+    the timing is not a chip's (CPU, or a kernel in interpret mode)."""
+    dev = jax.devices()[0]
+    if mode == "interpret" or dev.platform != "tpu":
+        return None
+    return bytes_per_s / peaks(dev.device_kind).hbm_bw
+
+
 def _bandwidth(moved_bytes: int, seconds: float, mode: str, *,
                log: Callable = print) -> dict:
     gbps = moved_bytes / seconds / 1e9 if seconds > 0 else 0.0
-    frac = gbps * 1e9 / HBM_BW
+    frac = roofline_share(gbps * 1e9, mode)
+    share = ("not measured (no chip timing)" if frac is None
+             else f"{frac * 100:.2f}% of peak HBM")
     log(f"  traffic {moved_bytes / 1e6:.1f} MB, achieved "
-        f"{gbps:.2f} GB/s = {frac * 100:.2f}% of roofline HBM "
-        f"({HBM_BW / 1e9:.0f} GB/s) [{mode} mode]")
+        f"{gbps:.2f} GB/s, roofline share {share} [{mode} mode]")
     return {"moved_bytes": moved_bytes, "achieved_gbps": gbps,
             "roofline_frac": frac, "mode": mode}

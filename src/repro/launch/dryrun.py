@@ -299,6 +299,7 @@ def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
         bytes_dev += (p - 2) * (2 * rows * K * 4 + cap * 12)
     wire = sum(c["wire_bytes"] for c in colls.values())
     ma = compiled.memory_analysis()
+    chip = RL.peaks(RL.DRYRUN_KIND)
     mesh_name = ("pod2x16x16" if multi_pod else "pod16x16")
     rec = {
         "arch": "gee-friendster", "shape": f"gee_{mode}", "mesh": mesh_name,
@@ -306,9 +307,9 @@ def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
         "flops_per_device": float(ca.get("flops", 0.0)),
         "bytes_per_device": bytes_dev,
         "collective_bytes": wire, "collectives": colls,
-        "compute_s": float(ca.get("flops", 0.0)) / RL.PEAK_FLOPS,
-        "memory_s": bytes_dev / RL.HBM_BW,
-        "collective_s": wire / RL.ICI_BW,
+        "compute_s": float(ca.get("flops", 0.0)) / chip.flops,
+        "memory_s": bytes_dev / chip.hbm_bw,
+        "collective_s": wire / chip.ici_bw,
         "arg_bytes": ma.argument_size_in_bytes,
         "temp_bytes": ma.temp_size_in_bytes,
         "model_edges": s,

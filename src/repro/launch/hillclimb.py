@@ -96,10 +96,11 @@ def _run_kernel_tune(fn: str, quick: bool) -> None:
     tuner = {"scatter": tune_scatter, "topk": tune_topk}[fn]
     kw = _KERNEL_QUICK[fn] if quick else {}
     out = tuner(**kw)
+    frac = out["roofline_frac"]
+    share = "not measured" if frac is None else f"{frac * 100:.2f}%"
     print(f"best[{fn}]: {out['best']}  {out['seconds'] * 1e3:.2f} ms  "
           f"{out['achieved_gbps']:.2f} GB/s "
-          f"({out['roofline_frac'] * 100:.2f}% roofline, "
-          f"{out['mode']} mode)")
+          f"(roofline share {share}, {out['mode']} mode)")
 
 
 def main():

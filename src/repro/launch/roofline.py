@@ -3,8 +3,8 @@
 Conventions (documented once, used everywhere):
   * ``cost_analysis()`` on an SPMD executable reports PER-DEVICE flops
     and bytes (verified empirically in this repo), so
-        compute_term_s = flops / PEAK_FLOPS
-        memory_term_s  = bytes / HBM_BW
+        compute_term_s = flops / peak FLOP/s
+        memory_term_s  = bytes / peak HBM bytes/s
     need no further division by chip count.
   * collective bytes are parsed from the compiled HLO: for every
     all-gather / all-reduce / reduce-scatter / all-to-all /
@@ -18,8 +18,8 @@ Conventions (documented once, used everywhere):
     (active) parameter count.  The ratio MODEL_FLOPS / (flops * chips)
     measures how much compiled compute is "useful".
 
-Hardware model (TPU v5e, per the brief):
-  197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+Hardware model: the published per-chip peaks in `PEAKS`, keyed by
+``jax.Device.device_kind``.  The dry run models a v5e (`DRYRUN_KIND`).
 """
 from __future__ import annotations
 
@@ -27,9 +27,35 @@ import re
 from dataclasses import dataclass
 from typing import Dict
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclass(frozen=True)
+class Peaks:
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    ici_bw: float         # chip-to-chip interconnect bytes/s
+    hbm_bytes: float      # HBM capacity
+
+
+#: Published peaks of one chip, by `device_kind`.  A device that is not
+#: here has no roofline: `peaks` raises rather than assume one.
+PEAKS: Dict[str, Peaks] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+    # of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+                         hbm_bytes=16e9),
+}
+
+#: the chip the dry-run roofline models
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of `device_kind` (``jax.devices()[0].device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -115,15 +141,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / peaks(DRYRUN_KIND).flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / peaks(DRYRUN_KIND).hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / peaks(DRYRUN_KIND).ici_bw
 
     @property
     def dominant(self) -> str:
@@ -144,12 +170,13 @@ class Roofline:
     @property
     def mfu(self) -> float:
         """MODEL_FLOPS / (step_s * chips * peak) — roofline fraction."""
-        denom = self.step_s * self.chips * PEAK_FLOPS
+        denom = self.step_s * self.chips * peaks(DRYRUN_KIND).flops
         return self.model_flops_global / denom if denom else 0.0
 
     @property
     def hbm_fit(self) -> bool:
-        return (self.arg_bytes + self.temp_bytes) <= 16e9
+        return (self.arg_bytes + self.temp_bytes
+                <= peaks(DRYRUN_KIND).hbm_bytes)
 
     def to_dict(self) -> dict:
         d = {k: getattr(self, k) for k in (

@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.query_fused import cosine_scores, topk_fused
+
 
 @jax.jit
 def gather_embeddings(Z, nodes):
@@ -93,7 +95,7 @@ def predict_rows(rows, centroids):
     Returns (pred, score)."""
     q = normalize_rows(rows)
     c = normalize_rows(centroids)
-    sims = q @ c.T
+    sims = cosine_scores(q, c)
     return jnp.argmax(sims, 1).astype(jnp.int32), jnp.max(sims, 1)
 
 
@@ -113,7 +115,7 @@ def _topk_block(vals, idxs, q, block, gidx, qnodes, *,
     BEFORE the block: with blocks presented in ascending-id order and
     ``lax.top_k``'s lower-position-wins tie rule, score ties resolve to
     the ascending global id (see the module tie-breaking contract)."""
-    scores = q @ block.T                                   # (q, B)
+    scores = cosine_scores(q, block)                       # (q, B)
     mask = gidx[None, :] < 0                               # padding rows
     if exclude_self:
         mask = mask | (gidx[None, :] == qnodes[:, None])
@@ -236,7 +238,6 @@ def topk_cosine_fused(Zn_rows, q, qnodes, *, k: int = 10,
     blocked merge is a single dispatch with the running top-k resident
     on-chip.  Candidate rows must be unit-norm (a shard's cached Zn);
     use `topk_cosine_fused_norm` on raw rows."""
-    from repro.kernels.query_fused import topk_fused
     m = Zn_rows.shape[0]
     vals, idxs = topk_fused(
         Zn_rows, q, qnodes, k=k, bucket=_bucket_rows(m, block_rows),
@@ -256,7 +257,6 @@ def topk_cosine_fused_norm(Z_rows, q, qnodes, *, k: int = 10,
     query result and the shard's Zn cache.  Returns (idx, vals, Zn);
     (idx, vals) are bit-identical to
     ``topk_cosine_q(normalize_rows(Z_rows), ...)``."""
-    from repro.kernels.query_fused import topk_fused
     m = Z_rows.shape[0]
     vals, idxs, Zn = topk_fused(
         Z_rows, q, qnodes, k=k, bucket=_bucket_rows(m, block_rows),

@@ -55,6 +55,7 @@ import argparse
 import numpy as np
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.core.gee import gee
 from repro.graph.edges import make_labels
 from repro.graph.generators import sbm
@@ -65,7 +66,7 @@ from repro.serving.store import GraphStore
 import jax.numpy as jnp
 
 
-def _self_check(engine: ServingEngine) -> float:
+def delta_rebuild_gap(engine: ServingEngine) -> float:
     """Max |delta-maintained Z - from-scratch Z| under epoch labels."""
     g = engine.store.edges()
     Z = gee(jnp.asarray(g.u), jnp.asarray(g.v), jnp.asarray(g.w),
@@ -134,6 +135,7 @@ def main(argv=None):
                     help="shut down remote workers at exit, including "
                          "--connect'ed ones")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.serve_shard is not None:
         # become worker `--shard-id` of the (n, shards) row partition:
@@ -225,7 +227,7 @@ def main(argv=None):
               f"mean_batch={row['mean_batch']:7.1f} "
               f"lat={row['mean_latency_ms']:8.2f} ms "
               f"thru={row['items_per_s']:10.0f} items/s")
-    err = _self_check(engine)
+    err = delta_rebuild_gap(engine)
     print(f"[serve-gee] self-check max|Z_delta - Z_rebuild| = {err:.2e}")
     assert err < 1e-3, "delta-maintained Z diverged from rebuild"
     if args.index:

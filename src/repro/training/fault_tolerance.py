@@ -36,6 +36,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 import jax
+from jax.sharding import AxisType
 
 
 class Heartbeat:
@@ -109,6 +110,7 @@ class ElasticMeshManager:
         data = n // model
         usable = data * model
         mesh = jax.make_mesh((data, model), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2,
                              devices=healthy_devices[:usable])
         self.generation += 1
         step = self.build_step(mesh)

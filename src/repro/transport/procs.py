@@ -17,6 +17,10 @@ resolves the same code regardless of how the parent was launched.
 
 Spawn is two-phase (``wait=False`` + :meth:`WorkerProc.handshake`) so a
 router bringing up N workers pays one jax-import latency, not N.
+
+One process per chip: on a TPU host, spawning refuses with a clear
+error (:func:`refuse_on_tpu`) instead of starting workers that would
+contend for the chip the router holds.
 """
 from __future__ import annotations
 
@@ -118,8 +122,25 @@ class WorkerProc:
             self.proc.stdout.close()
 
 
+def refuse_on_tpu() -> None:
+    """Spawned workers cannot share this host's TPU: a chip belongs to
+    one process, and the router, which has already touched JAX, holds
+    it — a worker would fail or hang at its first device call, and
+    pinning workers to the CPU would quietly serve from the wrong
+    device.  Refuse before anything starts, until each worker is given
+    a chip of its own."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise TransportError(
+            "socket transport cannot spawn shard or replica workers on "
+            "a TPU host: the router process holds the chip and a worker "
+            "would contend for it; use transport='local' (in-process "
+            "shards), or run the router with JAX_PLATFORMS=cpu")
+
+
 def _spawn(cmd: list, role: str, label: str, *,
            wait: bool, timeout_s: float) -> WorkerProc:
+    refuse_on_tpu()
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                             env=worker_env())
     wp = WorkerProc(proc, role, label)

@@ -38,6 +38,7 @@ from typing import Optional
 import numpy as np
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.transport.rpc import RpcServer, parse_addr
 
 
@@ -233,6 +234,7 @@ def _parse(argv=None) -> argparse.Namespace:
 
 def main(argv=None) -> int:
     args = _parse(argv)
+    enable_compile_cache()
     if args.obs is not None:             # explicit flag wins over env
         obs.configure(enabled=(args.obs == "on"))
     plan_cache = (None if args.plan_cache in ("off", "none")

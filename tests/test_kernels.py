@@ -70,7 +70,8 @@ class TestPackEdges:
         rows, clsb, valb, T = ops.pack_edges(dst, cls, val, n,
                                              tile_n, edge_block)
         assert rows.shape == clsb.shape == valb.shape
-        assert rows.shape[0] == T and rows.shape[2] == edge_block
+        # (T, BPT, 1, EB): the unit axis is the kernels' block row
+        assert rows.shape[0] == T and rows.shape[2:] == (1, edge_block)
         got = self._unpack_scatter(rows, clsb, valb, T, tile_n, n, K)
         np.testing.assert_allclose(
             got, self._scatter_oracle(dst, cls, val, n, K), atol=1e-6)

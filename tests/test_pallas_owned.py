@@ -179,6 +179,34 @@ class TestFusedTopK:
         assert np.array_equal(mr[0], mf[0])
         assert np.array_equal(mr[1], mf[1])
 
+    @pytest.mark.parametrize("normalize", [False, True])
+    def test_kernel_blocking_matches_scan(self, normalize, rng,
+                                          monkeypatch,
+                                          assert_topk_equivalent):
+        """The kernel's own row and query blocks (bounds shrunk so this
+        fixture spans several of each) keep the scan's answer: a
+        different matmul shape may move a score by an ulp on CPU, so
+        the comparison is tie-tolerant at 1e-6."""
+        from repro.kernels import query_fused as QF
+        monkeypatch.setattr(QF, "MAX_BLOCK_ROWS", 16)
+        monkeypatch.setattr(QF, "QUERY_BLOCK", 8)
+        QF.topk_fused.clear_cache()
+        try:
+            Z, Zn, q, qnodes = self._fixture(rng)
+            ref = Q.topk_cosine_q(Zn, q, qnodes, k=self.TOPK,
+                                  block_rows=64, row_offset=7)
+            if normalize:
+                fi, fv, Zn2 = Q.topk_cosine_fused_norm(
+                    jnp.asarray(Z), q, qnodes, k=self.TOPK,
+                    block_rows=64, row_offset=7)
+                assert np.array_equal(np.asarray(Zn), np.asarray(Zn2))
+            else:
+                fi, fv = Q.topk_cosine_fused(Zn, q, qnodes, k=self.TOPK,
+                                             block_rows=64, row_offset=7)
+            assert_topk_equivalent(fi, fv, ref[0], ref[1], atol=1e-6)
+        finally:
+            QF.topk_fused.clear_cache()
+
     def test_norm_mode_matches_separate_passes(self, rng):
         """Fused normalize+scan == normalize_rows -> blocked scan, and
         the emitted Zn is bit-identical to normalize_rows."""

@@ -469,6 +469,26 @@ def test_replica_reloads_on_checkpoint_generation_flip(tmp_path, rng):
 
 # -- multi-process deployments (spawn real workers) --------------------------
 
+@pytest.mark.parametrize("role", ["shard", "replica"])
+def test_socket_spawn_refuses_on_tpu_host(monkeypatch, tmp_path, role):
+    """The router holds the chip: spawning workers on a TPU host fails
+    loudly before any process starts (no hang, no silent CPU)."""
+    import jax
+    from repro.transport import procs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def no_spawn(*a, **k):
+        raise AssertionError("a worker process was started")
+
+    monkeypatch.setattr(procs.subprocess, "Popen", no_spawn)
+    with pytest.raises(TransportError, match="TPU host"):
+        if role == "shard":
+            ServingEngine(_mkstore(), num_shards=2, transport="socket",
+                          plan_cache=None)
+        else:
+            procs.spawn_replica_worker(str(tmp_path))
+
+
 @pytest.mark.slow
 def test_socket_engine_answers_equal_inprocess(tmp_path, rng):
     store_a, store_b = _mkstore(seed=11), _mkstore(seed=11)
