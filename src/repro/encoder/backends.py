@@ -149,18 +149,12 @@ class Backend:
         """Return (Z (n, K) float32, info dict)."""
         raise NotImplementedError
 
-    def _record_kernel(self, plan: Plan, Z, t0: float) -> None:
-        """Kernel-level throughput telemetry (obs-on only): fence the
-        result so the async dispatch is billed here, then export
-        achieved edges/s — the paper's own unit — as a gauge plus the
-        wall-time histogram."""
-        jax.block_until_ready(Z)
-        dt = obs.tock(t0)
-        obs.observe("repro_kernel_embed_seconds", dt,
-                    backend=self.name)
-        if plan.s and dt > 0:
-            obs.gauge("repro_kernel_edges_per_s", plan.s / dt,
-                      backend=self.name)
+    def slots(self, plan: Plan) -> Optional[int]:
+        """Contribution slots the device processes per embed, padding
+        included (`repro_kernel_slots_total`), from the plan's static
+        shapes; None where the backend does not count its work.  The
+        default is one slot per contribution: nothing padded."""
+        return int(plan.donor_counts().sum())
 
 
 def _owned_plan_host(graph: Graph, config: EncoderConfig,
@@ -315,17 +309,19 @@ class PallasBackend(Backend):
     def embed(self, plan, Yj, Wv):
         from repro.kernels.gee_scatter import gee_scatter_pallas
         d, cfg = plan.data, plan.config
-        t0 = obs.tick()
-        Ys = Yj[d["src"]]
-        cls = jnp.maximum(Ys, 0)
-        val = jnp.where(Ys >= 0, Wv[d["src"]] * d["w"], 0.0)
-        Z = gee_scatter_pallas(d["rows"], cls, val, num_tiles=d["T"],
-                               tile_n=cfg.tile_n, kdim=d["kdim"],
-                               interpret=d["interpret"])
-        Z = Z[:plan.n_local, :cfg.K]
-        if obs.enabled():
-            self._record_kernel(plan, Z, t0)
+        with obs.span("encoder.gather"):
+            Ys = Yj[d["src"]]
+            cls = jnp.maximum(Ys, 0)
+            val = jnp.where(Ys >= 0, Wv[d["src"]] * d["w"], 0.0)
+        with obs.span("encoder.scatter"):
+            Z = gee_scatter_pallas(d["rows"], cls, val, num_tiles=d["T"],
+                                   tile_n=cfg.tile_n, kdim=d["kdim"],
+                                   interpret=d["interpret"])
+            Z = Z[:plan.n_local, :cfg.K]
         return Z, {"interpret": d["interpret"]}
+
+    def slots(self, plan):
+        return int(plan.data["rows"].size)         # T * bpt * EB
 
 
 @register_backend("streaming")
@@ -371,7 +367,6 @@ class StreamingBackend(Backend):
     def embed(self, plan, Yj, Wv):
         from repro.core.gee import gee_streaming, gee_streaming_owned
         cfg = plan.config
-        t0 = obs.tick()
         if cfg.row_partition is not None:
             Z = gee_streaming_owned(
                 ((jnp.asarray(r), jnp.asarray(s), jnp.asarray(w))
@@ -382,9 +377,12 @@ class StreamingBackend(Backend):
                 ((jnp.asarray(u), jnp.asarray(v), jnp.asarray(w))
                  for (u, v, w) in plan.data["chunks"]),
                 Yj, K=cfg.K, n=plan.n, Wv=Wv)
-        if obs.enabled():
-            self._record_kernel(plan, Z, t0)
         return Z, {"chunks": len(plan.data["chunks"])}
+
+    def slots(self, plan):
+        rows = sum(c[0].shape[0] for c in plan.data["chunks"])
+        # unpartitioned chunk rows are edges: two contributions each
+        return rows if plan.config.row_partition is not None else 2 * rows
 
 
 class DistributedBackend(Backend):
@@ -430,6 +428,9 @@ class DistributedBackend(Backend):
                   "capacity_factor": float(p.host["capacity_factor"]),
                   "u": jnp.asarray(g.u), "v": jnp.asarray(g.v),
                   "w": jnp.asarray(g.w)}
+
+    def slots(self, plan):
+        return None            # capacity padding differs by mode
 
     def embed(self, plan, Yj, Wv):
         from repro.core.distributed import gee_sharded

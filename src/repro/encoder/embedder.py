@@ -220,28 +220,49 @@ class Embedder:
                 "live graph instead")
 
     def _embed(self, plan: Plan, Y) -> "Embedder":
-        Y = np.asarray(Y, np.int32)
-        if Y.shape != (plan.n,):
-            raise ValueError(f"Y shape {Y.shape} != ({plan.n},)")
-        if Y.size and Y.max() >= self.config.K:
-            raise ValueError(f"label {Y.max()} >= K={self.config.K}")
-        self.labels_ = Y.copy()
-        with obs.span("encoder.fit",
-                      metric="repro_encoder_fit_seconds",
-                      mlabels={"backend": self.backend.name},
-                      backend=self.backend.name, n=plan.n,
-                      s=plan.s) as sp:
+        with obs.span("encoder.fit", backend=self.backend.name,
+                      n=plan.n, s=plan.s) as sp:
+            Y = np.asarray(Y, np.int32)
+            if Y.shape != (plan.n,):
+                raise ValueError(f"Y shape {Y.shape} != ({plan.n},)")
+            if Y.size and Y.max() >= self.config.K:
+                raise ValueError(f"label {Y.max()} >= K={self.config.K}")
+            self.labels_ = Y.copy()
             self._Yj = jnp.asarray(Y)
             self._Yfit = self._Yj   # supervised set: pinned by refine()
             self.Wv_ = make_w(self._Yj, self.config.K)
             self.Z_, self.last_info_ = self.backend.embed(plan, self._Yj,
                                                           self.Wv_)
+            self._count_work(plan, Y)   # host work: overlaps the device
             sp.fence(self.Z_)       # bill the async scatter to the fit
-        if obs.enabled() and plan.s and sp.duration > 0:
-            obs.gauge("repro_encoder_fit_edges_per_s",
-                      plan.s / sp.duration, backend=self.backend.name)
+        if obs.enabled():       # a fit that raised is not timed
+            obs.observe("repro_encoder_fit_seconds", sp.duration,
+                        backend=self.backend.name)
+            if plan.s and sp.duration > 0:
+                obs.gauge("repro_encoder_fit_edges_per_s",
+                          plan.s / sp.duration, backend=self.backend.name)
         self._deltas_applied = 0
         return self
+
+    def _count_work(self, plan: Plan, Y: Optional[np.ndarray]) -> None:
+        """Count one embed's work from the plan's static shapes and the
+        host labels (obs-on only; nothing is read from the device):
+        the slots the device runs over, padding included, and the real
+        contributions split by whether their donor's label is known.
+        Y=None: every node carries a label (refinement rounds)."""
+        if not obs.enabled():
+            return
+        slots = self.backend.slots(plan)
+        if slots is None:
+            return
+        name, donors = self.backend.name, plan.donor_counts()
+        total = int(donors.sum())
+        labeled = total if Y is None else int(donors @ (Y >= 0))
+        obs.counter("repro_kernel_slots_total", slots, backend=name)
+        obs.counter("repro_kernel_contributions_total", labeled,
+                    backend=name, donor="labeled")
+        obs.counter("repro_kernel_contributions_total", total - labeled,
+                    backend=name, donor="unlabeled")
 
     def partial_fit(self, delta: Graph, *, sign: float = 1.0
                     ) -> "Embedder":
@@ -392,6 +413,7 @@ class Embedder:
             for _ in range(cfg.refine_iters):
                 Z, _ = self.backend.embed(self._plan, labels,
                                           make_w(labels, cfg.K))
+                self._count_work(self._plan, None)
                 labels = _kmeans_reassign(Z, labels, Y0, K=cfg.K,
                                           kmeans_iters=cfg.kmeans_iters)
             self.labels_ = np.asarray(labels)
@@ -399,6 +421,7 @@ class Embedder:
             self.Wv_ = make_w(labels, cfg.K)
             self.Z_, self.last_info_ = self.backend.embed(
                 self._plan, labels, self.Wv_)
+            self._count_work(self._plan, None)
             sp.fence(self.Z_)
         return self
 

@@ -100,6 +100,8 @@ class Plan:
     _u: Optional[np.ndarray] = None
     _v: Optional[np.ndarray] = None
     _w: Optional[np.ndarray] = None
+    #: per-node donor counts, built on first use (`donor_counts`)
+    _donors: Optional[np.ndarray] = None
 
     @property
     def n_local(self) -> int:
@@ -107,6 +109,26 @@ class Plan:
         (`n` stays the GLOBAL node count — labels are always (n,).)"""
         rp = self.config.row_partition
         return self.n if rp is None else rp[1] - rp[0]
+
+    def donor_counts(self) -> np.ndarray:
+        """How many contributions take their class and value from each
+        node's label: its degree, or under a row partition its edges
+        into the owned rows.  Label free, so built once per plan, by
+        the first embed that counts its work (obs on only); an embed's
+        labeled contributions are then ``donor_counts() @ (Y >= 0)``."""
+        if self._donors is None:
+            n, src = self.n, self.host.get("o_src")   # owned plans' donors
+            if src is not None:
+                self._donors = np.bincount(src, minlength=n)
+            else:
+                u, v = np.asarray(self._u), np.asarray(self._v)
+                rp = self.config.row_partition
+                if rp is not None:
+                    lo, hi = rp
+                    u, v = u[(v >= lo) & (v < hi)], v[(u >= lo) & (u < hi)]
+                self._donors = (np.bincount(u, minlength=n)
+                                + np.bincount(v, minlength=n))
+        return self._donors
 
     @classmethod
     def anchors(cls, graph: Graph) -> dict:

@@ -124,4 +124,5 @@ def gee_scatter_pallas(rows, cls, val, *, num_tiles: int, tile_n: int,
         out_specs=z_spec,
         out_shape=jax.ShapeDtypeStruct((T * tile_n, kdim), jnp.float32),
         interpret=interpret,
+        name="gee_scatter_pallas",     # the device trace's name for it
     )(rows, cls, val)

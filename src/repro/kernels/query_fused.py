@@ -202,6 +202,7 @@ def topk_fused(Z_rows, q, qnodes, *, k: int, bucket: int,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="topk_fused",
     )(Zp, qp, qn)
     vals, idxs = out[0][:nq], out[1][:nq]
     if normalize:
@@ -258,5 +259,6 @@ def gee_delta_renorm(Z, rows, cls, val, *, tile_n: int, eps: float = EPS,
         out_shape=[jax.ShapeDtypeStruct((T * tile_n, K), jnp.float32),
                    jax.ShapeDtypeStruct((T * tile_n, K), jnp.float32)],
         interpret=interpret,
+        name="gee_delta_renorm",
     )(jnp.asarray(rows), jnp.asarray(cls), jnp.asarray(val), Zp)
     return znew[:n_local], zn[:n_local]

@@ -14,7 +14,13 @@ that makes our reproduction's numbers legible):
   context managers producing parent-linked timed events into a
   bounded ring plus an optional JSONL sink, with
   ``sp.fence(device_array)`` jax block-until-ready fencing so async
-  device work is billed to the span that launched it.
+  device work is billed to the span that launched it.  Each span is
+  also a ``jax.profiler.TraceAnnotation``, so a profiler capture shows
+  it on the device trace's clock.
+* **compile counter** — a `jax.monitoring` listener registered on
+  import counts every backend compile (``repro_jax_compiles_total``,
+  ``repro_jax_compile_seconds``), so a window that should reuse its
+  programs can show that it did.
 * **export surfaces** — ``snapshot()`` (flat dict, the engine's
   ``stats()`` substrate), ``render_prometheus()`` (text exposition
   format), and the ``python -m repro.obs`` CLI (live demo snapshot /
@@ -23,9 +29,11 @@ that makes our reproduction's numbers legible):
 Enable/disable: **on by default**; ``REPRO_OBS=off`` (or ``0/none/
 disable(d)``) turns the whole layer into true no-ops — module-level
 helpers return before touching the registry, ``span()`` hands back a
-shared do-nothing singleton that never calls the clock and never
-blocks on device work.  The bench gate (`benchmarks.obs_gate`) holds
-the instrumented hot paths to within 3% of the disabled path.
+shared do-nothing singleton that never calls the clock, never
+annotates the profiler and never blocks on device work.  The bench
+gate (`benchmarks.obs_gate`) holds the instrumented hot paths to
+within 3% of the disabled path, but for the work counters' donor
+count, which the first counted embed of each plan pays (README).
 
 Environment:
 
@@ -39,7 +47,7 @@ Usage::
 
     obs.counter("repro_serving_wal_records_total")
     obs.observe("repro_serving_wal_append_seconds", dt)
-    obs.gauge("repro_kernel_edges_per_s", s / dt, backend="streaming")
+    obs.counter("repro_kernel_slots_total", slots, backend="pallas")
     with obs.span("serving.checkpoint",
                   metric="repro_serving_checkpoint_seconds") as sp:
         ...
@@ -50,6 +58,8 @@ from __future__ import annotations
 import os
 import time
 from typing import Any, Dict, Optional
+
+import jax.monitoring
 
 from repro.obs.registry import (MetricsRegistry, summarize,
                                 valid_metric_name)
@@ -80,6 +90,20 @@ _ENABLED: bool = _env_enabled()
 _REGISTRY = MetricsRegistry()
 _TRACER = Tracer(ring=_env_ring(),
                  trace_path=os.environ.get("REPRO_OBS_TRACE") or None)
+
+
+#: the `jax.monitoring` event recorded once per backend compile (every
+#: in-memory cache miss, persistent-cache hits included)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    if _ENABLED and event == COMPILE_EVENT:
+        _REGISTRY.counter("repro_jax_compiles_total")
+        _REGISTRY.observe("repro_jax_compile_seconds", duration)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def registry() -> MetricsRegistry:

@@ -7,6 +7,11 @@ A span is a context manager around one logical operation::
         sp.fence(plan_arrays)      # jax.block_until_ready: device work
                                    # is attributed to THIS span
 
+While the layer is enabled each span also enters and exits
+``jax.profiler.TraceAnnotation(name)`` (the name only; attributes stay
+in the ring), so every span lands on the profiler's host plane, on the
+device trace's clock, whenever a profiler is capturing.
+
 Spans nest per thread (a thread-local stack links each span to its
 parent), so a serving rebuild shows up as one ``serving.rebuild`` span
 with ``encoder.plan`` / ``encoder.fit`` children per shard.  On exit
@@ -35,6 +40,8 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Tracer:
@@ -130,7 +137,7 @@ class Span:
 
     __slots__ = ("tracer", "name", "attrs", "id", "parent", "t_wall",
                  "_t0", "duration", "thread", "error", "metric",
-                 "mlabels", "_registry")
+                 "mlabels", "_registry", "_ann")
 
     def __init__(self, tracer: Tracer, name: str,
                  attrs: Dict[str, Any]):
@@ -147,6 +154,7 @@ class Span:
         self.metric: Optional[str] = None
         self.mlabels: Dict[str, str] = {}
         self._registry = None
+        self._ann = TraceAnnotation(name)
 
     def set(self, **attrs) -> "Span":
         """Attach/override attributes mid-span."""
@@ -165,12 +173,14 @@ class Span:
         return value
 
     def __enter__(self) -> "Span":
+        self._ann.__enter__()
         self.t_wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.duration = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
         if exc is not None:
             self.error = repr(exc)
         self.tracer.end(self)

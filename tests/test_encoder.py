@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.ref_python import gee_numpy
 from repro.encoder import (Embedder, EncoderConfig, NotFittedError,
                            get_backend, list_backends, register_backend)
@@ -562,3 +563,108 @@ class TestServiceOnEmbedder:
         svc.compact()
         svc.refresh()
         assert svc.embedder.plan_stats["hits"] >= 1
+
+
+class TestWorkCounters:
+    """Each embed counts its work from the plan's static shapes and the
+    host labels: `repro_kernel_slots_total` (what the device runs over,
+    padding included) and `repro_kernel_contributions_total` split by
+    whether the donor's label is known."""
+
+    @pytest.fixture(autouse=True)
+    def fresh_obs(self):
+        obs.configure(enabled=True)
+        obs.reset()
+        yield
+        obs.reset()
+
+    @staticmethod
+    def _counts(backend):
+        r = obs.registry()
+        return (r.counter_value("repro_kernel_slots_total",
+                                backend=backend),
+                r.counter_value("repro_kernel_contributions_total",
+                                backend=backend, donor="labeled"),
+                r.counter_value("repro_kernel_contributions_total",
+                                backend=backend, donor="unlabeled"))
+
+    @staticmethod
+    def _pallas_slots(dst, n_rows):
+        """T * bpt * EB at CFG's tile_n = 64 and edge_block = 128."""
+        per_tile = np.bincount(dst // 64, minlength=-(-n_rows // 64))
+        return per_tile.size * max(1, -(-int(per_tile.max()) // 128)) * 128
+
+    @pytest.mark.parametrize("backend", ["pallas", "streaming"])
+    def test_fit_and_refit(self, backend):
+        g = erdos_renyi(300, 2000, seed=5, weighted=True)
+        rng = np.random.default_rng(5)
+        Ys = [make_labels(300, 4, 0.3, rng), make_labels(300, 4, 0.6, rng)]
+        if backend == "pallas":
+            slots = self._pallas_slots(np.concatenate([g.u, g.v]), 300)
+        else:
+            slots = 2 * 8 * 256     # 8 chunks of 256 edges, tail padded
+        emb = Embedder(EncoderConfig(K=4, **CFG), backend=backend)
+        labeled = 0
+        for i, Y in enumerate(Ys, 1):
+            if i == 1:
+                emb.fit(g, Y)
+            else:
+                emb.refit(Y)
+            labeled += int((Y[g.u] >= 0).sum() + (Y[g.v] >= 0).sum())
+            assert self._counts(backend) == (i * slots, labeled,
+                                             i * 2 * g.s - labeled)
+
+    @pytest.mark.parametrize("backend", ["pallas", "streaming"])
+    def test_row_partition_counts_owned_contributions(self, backend):
+        g = erdos_renyi(300, 2000, seed=6, weighted=True)
+        Y = make_labels(300, 4, 0.4, np.random.default_rng(6))
+        lo, hi = 100, 220
+        into_u, into_v = (g.u >= lo) & (g.u < hi), (g.v >= lo) & (g.v < hi)
+        dst = np.concatenate([g.u[into_u], g.v[into_v]]) - lo
+        donors = np.concatenate([g.v[into_u], g.u[into_v]])
+        emb = Embedder(EncoderConfig(K=4, row_partition=(lo, hi), **CFG),
+                       backend=backend)
+        emb.fit(g, Y)
+        slots, labeled, unlabeled = self._counts(backend)
+        assert labeled == (Y[donors] >= 0).sum()
+        assert labeled + unlabeled == dst.size
+        if backend == "pallas":
+            assert slots == self._pallas_slots(dst, hi - lo)
+        else:           # a chunk row is one contribution here
+            assert slots == -(-dst.size // 256) * 256
+
+    @pytest.mark.parametrize("backend", ["pallas", "streaming"])
+    def test_off_counts_nothing_and_builds_no_donor_counts(self, backend):
+        """With obs off the plan never pays for the donor counts."""
+        obs.configure(enabled=False)
+        g = erdos_renyi(300, 2000, seed=8)
+        Y = make_labels(300, 4, 0.3, np.random.default_rng(8))
+        emb = Embedder(EncoderConfig(K=4, **CFG), backend=backend)
+        emb.fit(g, Y).refit(Y)
+        assert emb._plan._donors is None
+        assert not obs.registry().series_names()
+
+    def test_rejected_labels_not_timed(self):
+        g = erdos_renyi(300, 2000, seed=9)
+        emb = Embedder(EncoderConfig(K=4, **CFG), backend="streaming")
+        with pytest.raises(ValueError, match="label"):
+            emb.fit(g, np.full(300, 4, np.int32))
+        assert obs.registry().hist_summary(
+            "repro_encoder_fit_seconds", backend="streaming")["count"] == 0
+        emb.fit(g, make_labels(300, 4, 0.3, np.random.default_rng(9)))
+        assert obs.registry().hist_summary(
+            "repro_encoder_fit_seconds", backend="streaming")["count"] == 1
+
+    def test_refine_counts_every_contribution_labeled(self):
+        g, truth = sbm(120, 3, 900, p_in=0.9, seed=7)
+        Y = make_labels(120, 3, 0.2, np.random.default_rng(7),
+                        true_labels=truth)
+        emb = Embedder(EncoderConfig(K=3, refine_iters=2, **CFG),
+                       backend="pallas").fit(g, Y)
+        before = self._counts("pallas")
+        emb.refine()
+        after = self._counts("pallas")
+        slots = self._pallas_slots(np.concatenate([g.u, g.v]), 120)
+        # refine_iters rounds plus the final embed, every donor labeled
+        assert [a - b for a, b in zip(after, before)] == \
+            [3 * slots, 3 * 2 * g.s, 0]

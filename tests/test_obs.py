@@ -11,6 +11,7 @@ distributions (write barriers INCLUDED — the bug this PR fixed), and
 """
 from __future__ import annotations
 
+import glob
 import json
 import os
 import re
@@ -18,10 +19,13 @@ import subprocess
 import sys
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
+from repro.encoder import Embedder, EncoderConfig
 from repro.obs.registry import bucket_index, bucket_upper
 from repro.obs.health import (DEGRADED, SERVING, STARTING, STATE_VALUES,
                               HealthTracker)
@@ -41,6 +45,32 @@ def clean_obs():
     yield
     obs.configure(enabled=True, trace_path="")
     obs.reset()
+
+
+def _profiled_host_names(tmp_path, body) -> set:
+    """Run `body` under a `jax.profiler` capture; the names of the
+    events on the profile's /host:CPU plane."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for e in line.events}
+
+
+def _pallas_fit_refit(rng):
+    """A small interpret-mode pallas fit, then a refit."""
+    g, truth = sbm(80, 3, 600, p_in=0.85, seed=int(rng.integers(1 << 31)))
+    emb = Embedder(EncoderConfig(K=3, tile_n=32, edge_block=128),
+                   backend="pallas")
+    emb.fit(g, make_labels(80, 3, 0.3, rng, true_labels=truth))
+    emb.refit(make_labels(80, 3, 0.6, rng, true_labels=truth))
+    jax.block_until_ready(emb.Z_)
 
 
 def _small_engine(rng, *, shards=2, n=60, data_dir=None, **kw):
@@ -209,6 +239,44 @@ class TestSpans:
         assert lines[1].startswith("  - child")  # indented under parent
         assert "shard=1" in lines[1]
 
+    def test_span_lands_on_profiler_host_plane(self, clean_obs,
+                                               tmp_path):
+        def body():
+            with obs.span("encoder.probe", rows=3):
+                with obs.span("encoder.inner"):
+                    pass
+        names = _profiled_host_names(tmp_path, body)
+        assert {"encoder.probe", "encoder.inner"} <= names
+        # attributes stay in the ring; the annotation is the name alone
+        assert obs.trace_events()[-1]["attrs"] == {"rows": 3}
+
+    def test_embed_layer_spans_on_profiler_host_plane(self, clean_obs,
+                                                      tmp_path, rng):
+        names = _profiled_host_names(tmp_path,
+                                     lambda: _pallas_fit_refit(rng))
+        assert {"encoder.plan", "encoder.fit", "encoder.gather",
+                "encoder.scatter"} <= names
+        ring = [e["name"] for e in obs.trace_events()]
+        assert ring.count("encoder.gather") == 2     # fit and refit
+        assert ring.count("encoder.scatter") == 2
+
+    def test_exit_on_another_thread(self, clean_obs):
+        sp = obs.span("encoder.handoff")
+        sp.__enter__()
+        errors = []
+
+        def finish():
+            try:
+                sp.__exit__(None, None, None)
+            except Exception as e:               # pragma: no cover
+                errors.append(e)
+        th = threading.Thread(target=finish)
+        th.start()
+        th.join()
+        assert not errors
+        assert [e["name"] for e in obs.trace_events()] == \
+            ["encoder.handoff"]
+
     def test_orphan_renders_as_root(self, clean_obs):
         tree = render_tree([{"name": "lost", "id": 7, "parent": 99,
                              "t0": 1.0, "dur_s": 0.0}])
@@ -237,6 +305,50 @@ class TestDisabled:
         eng, _ = _small_engine(rng, shards=1)
         eng.query_embed([0, 1])
         eng.close()
+        assert not obs.registry().series_names()
+
+    def test_span_not_annotated_when_off(self, clean_obs, tmp_path):
+        obs.configure(enabled=False)
+
+        def body():
+            with obs.span("encoder.probe"):
+                pass
+        assert "encoder.probe" not in _profiled_host_names(tmp_path, body)
+
+    def test_fit_and_refit_leave_no_trace_when_off(self, clean_obs,
+                                                   tmp_path, rng):
+        obs.configure(enabled=False)
+        names = _profiled_host_names(tmp_path,
+                                     lambda: _pallas_fit_refit(rng))
+        assert not obs.registry().series_names()
+        assert not obs.trace_events()
+        assert not [n for n in names if n.startswith("encoder.")]
+
+
+# -- compile counter ---------------------------------------------------------
+
+class TestCompileCounter:
+    @staticmethod
+    def _compiles():
+        return obs.registry().counter_value("repro_jax_compiles_total")
+
+    def test_counts_new_shapes_not_repeats(self, clean_obs):
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        a, b = jnp.ones(5), jnp.ones(7)
+        obs.reset()
+        f(a)
+        first = self._compiles()
+        assert first >= 1
+        f(a)
+        assert self._compiles() == first         # cached: no compile
+        f(b)
+        assert self._compiles() > first          # a new shape compiles
+        assert obs.registry().hist_summary(
+            "repro_jax_compile_seconds")["count"] == self._compiles()
+
+    def test_nothing_counted_when_off(self, clean_obs):
+        obs.configure(enabled=False)
+        jax.jit(lambda x: x - 2.0)(jnp.ones(3))
         assert not obs.registry().series_names()
 
 
