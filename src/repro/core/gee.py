@@ -24,13 +24,24 @@ import jax
 import jax.numpy as jnp
 
 
-def make_w(Y: jnp.ndarray, K: int) -> jnp.ndarray:
-    """Per-node projection weight: 1/|class(Y_i)| (0 for unlabeled)."""
+def class_weights(Y: jnp.ndarray, K: int) -> jnp.ndarray:
+    """Per-class projection weight (K,): 1/n_k for the n_k nodes labeled
+    k, 0 for a class no node carries.  The pallas backend applies it per
+    column of Z; `make_w` spreads it over the nodes."""
     labeled = Y >= 0
     counts = jnp.zeros(K, jnp.float32).at[jnp.where(labeled, Y, 0)].add(
         labeled.astype(jnp.float32))
-    inv = jnp.where(counts > 0, 1.0 / jnp.maximum(counts, 1.0), 0.0)
-    return jnp.where(labeled, inv[jnp.maximum(Y, 0)], 0.0)
+    return jnp.where(counts > 0, 1.0 / jnp.maximum(counts, 1.0), 0.0)
+
+
+def make_w(Y: jnp.ndarray, K: int,
+           class_w: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Per-node projection weight: 1/|class(Y_i)| (0 for unlabeled),
+    which the scatter backends apply per contribution.  `class_w`: the
+    `class_weights(Y, K)` the caller already holds."""
+    if class_w is None:
+        class_w = class_weights(Y, K)
+    return jnp.where(Y >= 0, class_w[jnp.maximum(Y, 0)], 0.0)
 
 
 def edge_contributions(u, v, w, Y, Wv):

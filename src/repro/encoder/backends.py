@@ -36,6 +36,7 @@ entirely; that is the whole point of the persistent tier.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Tuple, Type
 
 import jax
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core.gee import make_w
 from repro.encoder.config import EncoderConfig
 from repro.encoder.plan import Plan, effective_weights, owned_contributions
 from repro.graph.edges import Graph
@@ -144,9 +146,15 @@ class Backend:
         self.plan_finalize(p, graph, mesh=mesh)
         return p
 
-    def embed(self, plan: Plan, Yj: jnp.ndarray, Wv: jnp.ndarray
+    def embed(self, plan: Plan, Yj: jnp.ndarray, class_w: jnp.ndarray
               ) -> Tuple[jnp.ndarray, dict]:
-        """Return (Z (n, K) float32, info dict)."""
+        """Return (Z (n, K) float32, info dict).
+
+        class_w: the (K,) class weights 1/n_k of the labels Yj
+        (`core.gee.class_weights`).  A contribution's weight depends
+        only on its class, which is its column of Z: pallas scales Z's
+        columns by class_w; the other backends weight each contribution
+        by the per-node `make_w(Yj, K, class_w)`."""
         raise NotImplementedError
 
     def slots(self, plan: Plan) -> Optional[int]:
@@ -187,11 +195,12 @@ class NumpyBackend(Backend):
                       "src": np.asarray(h["o_src"], np.int32),
                       "w": np.asarray(h["o_w"], np.float32)}
 
-    def embed(self, plan, Yj, Wv):
+    def embed(self, plan, Yj, class_w):
         from repro.core.ref_python import gee_numpy, gee_numpy_owned
         Y = np.asarray(Yj)
         d = plan.data
         if plan.config.row_partition is not None:
+            Wv = make_w(Yj, plan.config.K, class_w)
             Z = gee_numpy_owned(d["rows"], d["src"], d["w"], Y,
                                 np.asarray(Wv), plan.config.K,
                                 plan.n_local)
@@ -204,8 +213,8 @@ class NumpyBackend(Backend):
 @register_backend("xla")
 class XlaBackend(Backend):
     """`core.gee` (jitted XLA scatter-add) — the single-device hot
-    path.  Passes the Embedder-owned Wv through `gee`'s precompute
-    parameter instead of re-deriving it from Y.  Under a row partition
+    path.  Builds Wv from the Embedder's class weights and passes it
+    through `gee`'s precompute parameter.  Under a row partition
     it scatters the pre-bucketed owned contributions into an
     (n_local, K) accumulator (`core.gee.gee_owned`)."""
 
@@ -230,9 +239,10 @@ class XlaBackend(Backend):
                       "w": jnp.asarray(np.asarray(h["o_w"],
                                                   np.float32))}
 
-    def embed(self, plan, Yj, Wv):
+    def embed(self, plan, Yj, class_w):
         from repro.core.gee import gee, gee_owned
         d = plan.data
+        Wv = make_w(Yj, plan.config.K, class_w)
         if plan.config.row_partition is not None:
             Z = gee_owned(d["rows"], d["src"], d["w"], Yj, Wv,
                           K=plan.config.K, n_local=plan.n_local)
@@ -242,16 +252,29 @@ class XlaBackend(Backend):
         return Z, {}
 
 
+@functools.partial(jax.jit, static_argnames=("n",))
+def _scale_columns(Z, class_w, *, n: int):
+    """The kernel's padded accumulator cut to n rows and K =
+    class_w.size columns, column k scaled by class k's weight 1/n_k."""
+    return Z[:n, :class_w.shape[0]] * class_w
+
+
 @register_backend("pallas")
 class PallasBackend(Backend):
     """Destination-tiled one-hot matmul kernel.
 
     The plan packs (tile-local row, source node, weight) — all
-    label-free — so refits resolve classes/values on device from the
-    current (Y, Wv) and skip the O(s log s) host sort entirely.  Padded
-    slots carry w = 0 and are no-ops for any labeling.  The packed
-    buffers are the host half: a persistent-cache hit skips the sort in
-    a fresh process too.
+    label-free — so refits resolve each slot's class on device from the
+    current Y, with one gather over the packed slots, and skip the
+    O(s log s) host sort entirely.  The kernel scatters the packed
+    weight under that class; an unlabeled donor's class -1 matches no
+    column of the kernel's one-hot, so it adds nothing.  The projection
+    weight 1/n_k depends only on the class, which is the contribution's
+    column of Z, so it is applied once per column of the (n_local, K)
+    result (`class_w`), not per slot.  Padded slots
+    carry w = 0 and are no-ops for any labeling.  The packed buffers
+    are the host half: a persistent-cache hit skips the sort in a fresh
+    process too.
 
     Under a row partition the contributions bucketed by owned
     destination (`plan.owned_contributions`, destinations remapped to
@@ -306,18 +329,16 @@ class PallasBackend(Backend):
                       1.0 if interp else 0.0,
                       mode=interpret_mode_name(interp))
 
-    def embed(self, plan, Yj, Wv):
+    def embed(self, plan, Yj, class_w):
         from repro.kernels.gee_scatter import gee_scatter_pallas
         d, cfg = plan.data, plan.config
         with obs.span("encoder.gather"):
             Ys = Yj[d["src"]]
-            cls = jnp.maximum(Ys, 0)
-            val = jnp.where(Ys >= 0, Wv[d["src"]] * d["w"], 0.0)
         with obs.span("encoder.scatter"):
-            Z = gee_scatter_pallas(d["rows"], cls, val, num_tiles=d["T"],
+            Z = gee_scatter_pallas(d["rows"], Ys, d["w"], num_tiles=d["T"],
                                    tile_n=cfg.tile_n, kdim=d["kdim"],
                                    interpret=d["interpret"])
-            Z = Z[:plan.n_local, :cfg.K]
+            Z = _scale_columns(Z, class_w, n=plan.n_local)
         return Z, {"interpret": d["interpret"]}
 
     def slots(self, plan):
@@ -327,7 +348,7 @@ class PallasBackend(Backend):
 @register_backend("streaming")
 class StreamingBackend(Backend):
     """`gee_streaming`'s accumulate loop over bucket-padded chunks, with
-    the Embedder-owned Wv: bounded DEVICE working set — each chunk is
+    Wv built from the Embedder's class weights: bounded DEVICE working set — each chunk is
     uploaded, folded into Z, and released, so only O(chunk) edge data
     plus Z ever lives on device (the serving-rebuild and out-of-core
     ingestion path).  Chunks stay host-side in the plan (non-tail
@@ -364,9 +385,10 @@ class StreamingBackend(Backend):
                 np.asarray(h["o_w"], np.float32),
                 p.config.chunk_size))}
 
-    def embed(self, plan, Yj, Wv):
+    def embed(self, plan, Yj, class_w):
         from repro.core.gee import gee_streaming, gee_streaming_owned
         cfg = plan.config
+        Wv = make_w(Yj, cfg.K, class_w)
         if cfg.row_partition is not None:
             Z = gee_streaming_owned(
                 ((jnp.asarray(r), jnp.asarray(s), jnp.asarray(w))
@@ -432,7 +454,7 @@ class DistributedBackend(Backend):
     def slots(self, plan):
         return None            # capacity padding differs by mode
 
-    def embed(self, plan, Yj, Wv):
+    def embed(self, plan, Yj, class_w):
         from repro.core.distributed import gee_sharded
         d, cfg = plan.data, plan.config
         Y_pad = jnp.concatenate([

@@ -21,11 +21,14 @@ Design rules:
   skips host packing too and only re-runs cheap device placement
   (`plan_stats` counts built / hits / disk_hits / disk_stores; the
   encoder benchmark measures both tiers).
-* **The Embedder owns the projection weights.**  `make_w(Y, K)` is
-  computed at fit time and used by every subsequent `partial_fit`, so
-  the raw `gee_apply_delta` contract — "Wv must be the weights Z was
-  built with" — can no longer be violated by a caller holding a stale
-  or foreign Wv.
+* **The Embedder owns the projection weights.**  The class weights
+  `class_weights(Y, K)` are computed once per embed and handed to the
+  backend (pallas applies 1/n_k per column of Z, the others per
+  contribution through `make_w`).  The per-node `Wv_` every subsequent
+  `partial_fit` uses is built from them on first use, so the raw
+  `gee_apply_delta` contract — "Wv must be the weights Z was built
+  with" — can no longer be violated by a caller holding a stale or
+  foreign Wv.
 """
 from __future__ import annotations
 
@@ -39,8 +42,9 @@ import numpy as np
 import functools
 
 from repro import obs
-from repro.core.gee import (gee_apply_delta, gee_apply_delta_owned,
-                            kmeans_refine_round, make_w)
+from repro.core.gee import (class_weights, gee_apply_delta,
+                            gee_apply_delta_owned, kmeans_refine_round,
+                            make_w)
 from repro.encoder.backends import Backend, get_backend, resolve_auto
 from repro.encoder.config import EncoderConfig
 from repro.encoder.plan import Plan, owned_contributions
@@ -65,7 +69,8 @@ class Embedder:
     Fitted state (sklearn-style trailing underscore):
       Z_        (n, K) float32 embedding (device array).
       labels_   the labels Z was built under (int32, -1 = unknown).
-      Wv_       per-node projection weights Z was built with.
+      Wv_       per-node projection weights Z was built with (built
+                from the class weights on first read).
     """
 
     def __init__(self, config: EncoderConfig, *,
@@ -91,7 +96,8 @@ class Embedder:
         self._Yj = self._Yfit = None
         self.Z_: Optional[jnp.ndarray] = None
         self.labels_: Optional[np.ndarray] = None
-        self.Wv_: Optional[jnp.ndarray] = None
+        self._class_w: Optional[jnp.ndarray] = None   # (K,) 1/n_k
+        self._Wv: Optional[jnp.ndarray] = None        # Wv_, once read
         self.last_info_: dict = {}
         self.plan_stats = {"built": 0, "hits": 0,
                            "disk_hits": 0, "disk_stores": 0}
@@ -156,7 +162,7 @@ class Embedder:
             # the fitted state belonged to the OLD plan's graph; keeping
             # it would let refit()/transform() serve stale or mismatched
             # results against the new plan
-            self.Z_ = self.labels_ = self.Wv_ = None
+            self.Z_ = self.labels_ = self._class_w = self._Wv = None
             self._Yj = self._Yfit = None
             self._deltas_applied = 0
             self.last_info_ = {}
@@ -228,11 +234,8 @@ class Embedder:
             if Y.size and Y.max() >= self.config.K:
                 raise ValueError(f"label {Y.max()} >= K={self.config.K}")
             self.labels_ = Y.copy()
-            self._Yj = jnp.asarray(Y)
+            self._project(plan, jnp.asarray(Y))
             self._Yfit = self._Yj   # supervised set: pinned by refine()
-            self.Wv_ = make_w(self._Yj, self.config.K)
-            self.Z_, self.last_info_ = self.backend.embed(plan, self._Yj,
-                                                          self.Wv_)
             self._count_work(plan, Y)   # host work: overlaps the device
             sp.fence(self.Z_)       # bill the async scatter to the fit
         if obs.enabled():       # a fit that raised is not timed
@@ -243,6 +246,20 @@ class Embedder:
                           plan.s / sp.duration, backend=self.backend.name)
         self._deltas_applied = 0
         return self
+
+    def _project(self, plan: Plan, Yj: jnp.ndarray) -> None:
+        """Embed under device labels Yj and keep it as the fitted
+        state; `Wv_` is built from the class weights when first read."""
+        self._Yj, self._Wv = Yj, None
+        self._class_w = class_weights(Yj, self.config.K)
+        self.Z_, self.last_info_ = self.backend.embed(plan, Yj,
+                                                      self._class_w)
+
+    @property
+    def Wv_(self) -> Optional[jnp.ndarray]:
+        if self._Wv is None and self._class_w is not None:
+            self._Wv = make_w(self._Yj, self.config.K, self._class_w)
+        return self._Wv
 
     def _count_work(self, plan: Plan, Y: Optional[np.ndarray]) -> None:
         """Count one embed's work from the plan's static shapes and the
@@ -412,15 +429,12 @@ class Embedder:
             labels = jnp.where(Y0 >= 0, Y0, rand)
             for _ in range(cfg.refine_iters):
                 Z, _ = self.backend.embed(self._plan, labels,
-                                          make_w(labels, cfg.K))
+                                          class_weights(labels, cfg.K))
                 self._count_work(self._plan, None)
                 labels = _kmeans_reassign(Z, labels, Y0, K=cfg.K,
                                           kmeans_iters=cfg.kmeans_iters)
             self.labels_ = np.asarray(labels)
-            self._Yj = labels
-            self.Wv_ = make_w(labels, cfg.K)
-            self.Z_, self.last_info_ = self.backend.embed(
-                self._plan, labels, self.Wv_)
+            self._project(self._plan, labels)
             self._count_work(self._plan, None)
             sp.fence(self.Z_)
         return self

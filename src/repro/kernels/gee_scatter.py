@@ -24,9 +24,9 @@ two dimensions (1, EB) — equal to the array's own and a multiple of
 be a multiple of 128 for the compiled kernel (any size interprets).
 
 The matmul runs at ``Precision.HIGHEST``: the one-hot is exact in any
-precision but the values (1/class-count times the edge weight) are
-not exact in bf16, and the f32 contract with the numpy oracle is what
-a caller gets from every other backend.
+precision but the values (edge weights, times 1/class-count on the
+delta path) are not exact in bf16, and the f32 contract with the
+numpy oracle is what a caller gets from every other backend.
 
 This mirrors how the paper's cache analysis maps to the TPU memory
 hierarchy: their "Z(u,:) stays in processor cache during a vertex's edge

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro import obs
+from repro.core.gee import make_w
 from repro.core.ref_python import gee_numpy
 from repro.encoder import (Embedder, EncoderConfig, NotFittedError,
                            get_backend, list_backends, register_backend)
@@ -50,6 +51,9 @@ def _cases():
     Y3 = np.full(90, -1, np.int32)                       # 3 labeled nodes
     Y3[[0, 7, 31]] = [0, 1, 2]
     cases["sparsely_labeled"] = (g3, Y3)
+    Y4 = make_labels(130, 5, 0.4, rng)              # no node carries 2
+    cases["empty_class"] = (g, np.where(Y4 == 2, -1, Y4).astype(np.int32))
+    cases["all_unlabeled"] = (g3, np.full(90, -1, np.int32))
     return cases
 
 
@@ -62,9 +66,12 @@ class TestConformance:
         emb = Embedder(EncoderConfig(K=K, **CFG), backend=backend)
         emb.fit(g, Y)
         atol = 1e-5 if emb.backend.exact else 1e-4
-        np.testing.assert_allclose(emb.transform(), _oracle(g, Y, K),
-                                   atol=atol)
+        Z = emb.transform()
+        np.testing.assert_allclose(Z, _oracle(g, Y, K), atol=atol)
         assert emb.last_info_.get("dropped", 0) == 0
+        # a class no node carries weighs 0, not 1/0: its column is 0
+        absent = np.setdiff1d(np.arange(K), Y)
+        assert np.isfinite(Z).all() and np.all(Z[:, absent] == 0)
 
     @pytest.mark.parametrize("backend", ALL_BACKENDS)
     def test_laplacian_conformance(self, backend):
@@ -668,3 +675,52 @@ class TestWorkCounters:
         # refine_iters rounds plus the final embed, every donor labeled
         assert [a - b for a, b in zip(after, before)] == \
             [3 * slots, 3 * 2 * g.s, 0]
+
+
+class TestClassAxisWeights:
+    """The pallas backend scatters the packed edge weight under each
+    slot's class and scales Z's columns by the class weights 1/n_k once,
+    instead of weighting every slot by its donor's Wv."""
+
+    def test_refine_matches_oracle(self):
+        g, Y = _cases()["weighted_directed"]
+        emb = Embedder(EncoderConfig(K=5, refine_iters=2, **CFG),
+                       backend="pallas", plan_cache=None).fit(g, Y)
+        emb.refine(jax.random.PRNGKey(2))
+        labels = np.asarray(emb.labels_)
+        np.testing.assert_allclose(np.asarray(emb.Z_),
+                                   _oracle(g, labels, 5), atol=1e-5)
+        # the delta paths' per-node weights follow the refined labels
+        np.testing.assert_array_equal(
+            np.asarray(emb.Wv_),
+            np.asarray(make_w(jnp.asarray(labels), 5)))
+
+    def test_one_gather_over_the_packed_slots(self):
+        """Only the donor's label is gathered per packed slot: the
+        weight 1/n_k is applied per column, so no second slot-sized
+        gather (of Wv) is traced."""
+        from repro.core.gee import class_weights
+        from jax.extend.core import ClosedJaxpr, Jaxpr
+        g, Y = _cases()["weighted_directed"]
+        emb = Embedder(EncoderConfig(K=5, **CFG), backend="pallas",
+                       plan_cache=None).fit(g, Y)
+        plan, slots = emb._plan, emb._plan.data["rows"].size
+        Yj = jnp.asarray(Y)
+        closed = jax.make_jaxpr(
+            lambda y, c: emb.backend.embed(plan, y, c)[0]
+        )(Yj, class_weights(Yj, 5))
+
+        def eqns(jaxpr):
+            for e in jaxpr.eqns:
+                yield e
+                for p in e.params.values():
+                    for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                        if isinstance(sub, ClosedJaxpr):
+                            yield from eqns(sub.jaxpr)
+                        elif isinstance(sub, Jaxpr):
+                            yield from eqns(sub)
+
+        gathers = [e for e in eqns(closed.jaxpr)
+                   if e.primitive.name == "gather"
+                   and e.invars[1].aval.size == slots]
+        assert len(gathers) == 1
