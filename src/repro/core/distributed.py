@@ -24,6 +24,12 @@ reduction modes, all computing bit-identical Z:
                   TPU-native replacement for atomics: deterministic
                   neighbor exchanges instead of racing writes.
 
+Each mode is one jitted program, built once per (mode, K, n, mesh,
+capacity, laplacian) and cached (`_program`), so a refit dispatches one
+executable.  The edges arrive sharded over the mesh's edge axis; the
+labels and the (K,) class weights 1/n_k arrive replicated, and each
+contribution takes its donor's class weight by the donor's label.
+
 Bucketed modes use capacity padding (cap = mean * capacity_factor).
 With randomly-shuffled edges, bucket sizes concentrate tightly around
 the mean; overflow is *counted and returned* so callers can assert
@@ -39,9 +45,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.gee import edge_contributions, make_w
+from repro.core.gee import class_weights
 
 AXIS = "edges"
+#: modes that scatter every padded edge's two contributions in place,
+#: with no capacity padding
+SCATTER_MODES = ("replicated", "reduce_scatter")
 
 
 def shard_map(f, mesh, in_specs, out_specs):
@@ -94,6 +103,23 @@ def _bucket_by_owner(dst, cls, val, rows: int, p: int, cap: int):
     return b_row, b_cls, b_val, dropped
 
 
+def _contributions(u, v, w, Y, class_w):
+    """Per-directed-edge (dst, class, value) pairs, both directions, as
+    `core.gee.edge_contributions` gives them, with each donor's weight
+    read from the (K,) class weights by its label: the value
+    `make_w(Y, K, class_w)` holds for it, bit for bit.  On the TPU one
+    jitted program prefetches one gather table into fast memory (the
+    labels); gathering a second (n,) table, the per-node weights, would
+    read HBM for every contribution."""
+    yv, yu = Y[v], Y[u]
+    cv, cu = jnp.maximum(yv, 0), jnp.maximum(yu, 0)
+    dst = jnp.concatenate([u, v])
+    cls = jnp.concatenate([cv, cu])
+    val = jnp.concatenate([jnp.where(yv >= 0, class_w[cv] * w, 0.0),
+                           jnp.where(yu >= 0, class_w[cu] * w, 0.0)])
+    return dst, cls, val
+
+
 def _scatter_rows(rows: int, K: int, r, c, v):
     return jnp.zeros((rows, K), jnp.float32).at[r, c].add(v)
 
@@ -103,22 +129,22 @@ def _scatter_rows(rows: int, K: int, r, c, v):
 # ---------------------------------------------------------------------------
 
 
-def _body_replicated(u, v, w, Y, Wv, *, K, n):
-    dst, cls, val = edge_contributions(u, v, w, Y, Wv)
+def _body_replicated(u, v, w, Y, class_w, *, K, n):
+    dst, cls, val = _contributions(u, v, w, Y, class_w)
     Z = _scatter_rows(n, K, dst, cls, val)
     return jax.lax.psum(Z, AXIS), jnp.zeros((), jnp.int32)
 
 
-def _body_reduce_scatter(u, v, w, Y, Wv, *, K, n, p):
-    dst, cls, val = edge_contributions(u, v, w, Y, Wv)
+def _body_reduce_scatter(u, v, w, Y, class_w, *, K, n, p):
+    dst, cls, val = _contributions(u, v, w, Y, class_w)
     Z = _scatter_rows(n, K, dst, cls, val)
     Zs = jax.lax.psum_scatter(Z, AXIS, scatter_dimension=0, tiled=True)
     return Zs, jnp.zeros((), jnp.int32)
 
 
-def _body_a2a(u, v, w, Y, Wv, *, K, n, p, cap):
+def _body_a2a(u, v, w, Y, class_w, *, K, n, p, cap):
     rows = n // p
-    dst, cls, val = edge_contributions(u, v, w, Y, Wv)
+    dst, cls, val = _contributions(u, v, w, Y, class_w)
     b_row, b_cls, b_val, dropped = _bucket_by_owner(dst, cls, val, rows, p,
                                                     cap)
     r = jax.lax.all_to_all(b_row, AXIS, split_axis=0, concat_axis=0,
@@ -131,14 +157,15 @@ def _body_a2a(u, v, w, Y, Wv, *, K, n, p, cap):
     return Z, jax.lax.psum(dropped, AXIS)
 
 
-def _body_a2a_prebucketed(b_dst, b_cls, b_wv, Y, Wv, *, K, n, p):
+def _body_a2a_prebucketed(b_dst, b_cls, b_wv, Y, class_w, *, K, n, p):
     """Steady-state a2a: buckets were built once at ingestion (the owner
     of a contribution depends only on the destination node, not on the
     labels), so refinement iterations skip the sort entirely.  b_* are
     (p, cap) per-owner buckets of (local_row, class-source node, weight).
     Class/value are resolved per iteration from the CURRENT labels."""
-    cls = jnp.maximum(Y[b_cls], 0)
-    val = jnp.where(Y[b_cls] >= 0, Wv[b_cls] * b_wv, 0.0)
+    y = Y[b_cls]
+    cls = jnp.maximum(y, 0)
+    val = jnp.where(y >= 0, class_w[cls] * b_wv, 0.0)
     r = jax.lax.all_to_all(b_dst, AXIS, split_axis=0, concat_axis=0)
     c = jax.lax.all_to_all(cls, AXIS, split_axis=0, concat_axis=0)
     x = jax.lax.all_to_all(val, AXIS, split_axis=0, concat_axis=0)
@@ -186,19 +213,28 @@ def gee_a2a_steady(b_dst, b_src, b_w, Y, *, K: int, n_pad: int, mesh: Mesh):
 
     b_* are the (p, p, cap) host buckets flattened to (p*p, cap) so the
     leading dim shards p-ways (each shard gets its (p, cap) slab)."""
-    p = mesh.shape[AXIS]
-    Wv = make_w(Y, K)
-    body = functools.partial(_body_a2a_prebucketed, K=K, n=n_pad, p=p)
+    return _steady_program(K, n_pad, mesh)(b_dst, b_src, b_w, Y)
+
+
+@functools.lru_cache(maxsize=16)
+def _steady_program(K: int, n_pad: int, mesh: Mesh):
+    """`gee_a2a_steady`'s jitted program, built once per (K, n_pad,
+    mesh)."""
+    body = functools.partial(_body_a2a_prebucketed, K=K, n=n_pad,
+                             p=mesh.shape[AXIS])
     fn = shard_map(body, mesh,
                    in_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P()),
                    out_specs=(P(AXIS, None), P()))
-    return fn(b_dst, b_src, b_w, Y, Wv)
+
+    def run(b_dst, b_src, b_w, Y):
+        return fn(b_dst, b_src, b_w, Y, class_weights(Y, K))
+    return jax.jit(run)
 
 
-def _body_ring(u, v, w, Y, Wv, *, K, n, p, cap):
+def _body_ring(u, v, w, Y, class_w, *, K, n, p, cap):
     rows = n // p
     me = jax.lax.axis_index(AXIS)
-    dst, cls, val = edge_contributions(u, v, w, Y, Wv)
+    dst, cls, val = _contributions(u, v, w, Y, class_w)
     b_row, b_cls, b_val, dropped = _bucket_by_owner(dst, cls, val, rows, p,
                                                     cap)
 
@@ -224,53 +260,101 @@ def _body_ring(u, v, w, Y, Wv, *, K, n, p, cap):
 # ---------------------------------------------------------------------------
 
 
-def gee_sharded(u, v, w, Y, *, K: int, n: int, mesh: Mesh,
+def bucket_cap(mode: str, s_local: int, p: int,
+               capacity_factor: float) -> int:
+    """Slots of one (shard, owner) bucket in the bucketed modes: the
+    mean 2 * s_local / p contributions times the capacity factor, + 8;
+    0 in the scatter modes, which bucket nothing."""
+    if mode in SCATTER_MODES:
+        return 0
+    return int(np.ceil(2 * s_local / p * capacity_factor)) + 8
+
+
+def embed_slots(mode: str, s_pad: int, p: int, cap: int) -> int:
+    """Contribution slots all chips scatter in one embed, padding
+    included: the 2 * s_pad contributions of the padded edges in the
+    scatter modes, p * p buckets of `cap` in the bucketed ones."""
+    return 2 * s_pad if mode in SCATTER_MODES else p * p * cap
+
+
+def collective_bytes(mode: str, n_pad: int, K: int, p: int, cap: int
+                     ) -> int:
+    """The least bytes one chip must send for one embed's collective,
+    as the algorithm states it, from static shapes (f32 Z, int32/f32
+    buckets; the drop count's scalar psum is left out).  The collective
+    XLA lowers may send more: on TPU v5e `psum_scatter` of the (n_pad,
+    K) accumulator lowers to an all-reduce and a slice, twice the
+    reduce-scatter's bytes, so a bandwidth share taken from this count
+    is a share of the minimum:
+
+      replicated      all-reduce of (n_pad, K): 2 (p-1)/p * n_pad*K*4
+      reduce_scatter  reduce-scatter of (n_pad, K): (p-1)/p * n_pad*K*4
+      a2a             three all_to_alls of (p, cap) int32/int32/f32
+                      buckets, all but its own: (p-1) * cap * 12
+      ring            p-1 collective_permutes of the (n_pad/p, K)
+                      accumulator: (p-1) * n_pad/p * K*4
+    """
+    z = n_pad * K * 4
+    return {"replicated": 2 * (p - 1) * z // p,
+            "reduce_scatter": (p - 1) * z // p,
+            "a2a": (p - 1) * cap * 12,
+            "ring": (p - 1) * z // p}[mode]
+
+
+def gee_sharded(u, v, w, Y, class_w, *, K: int, n: int, mesh: Mesh,
                 mode: str = "ring", capacity_factor: float = 2.0,
                 laplacian: bool = False):
-    """Distributed GEE under shard_map.
+    """Distributed GEE under shard_map, one jitted program per (mode,
+    K, n, mesh, bucket capacity, laplacian) (`_program`).
 
     u, v, w: (s,) edge arrays, s divisible by mesh size (pad first —
-    `Graph.pad_to`).  Y: (n_pad,) labels, n divisible by mesh size for
-    row-sharded modes.  Returns (Z, dropped):
+    `Graph.pad_to`); sharded over the mesh's edge axis, each chip holds
+    its s/p.  Y: (n_pad,) labels, n divisible by mesh size for
+    row-sharded modes; class_w: (K,) class weights 1/n_k of Y
+    (`core.gee.class_weights`), which weight each contribution by its
+    donor's class.  Returns (Z, dropped):
       replicated          -> Z (n, K) replicated
       others              -> Z (n, K) row-sharded over the mesh
     """
     p = mesh.shape[AXIS]
     assert u.shape[0] % p == 0, (u.shape, p)
-    w = w.astype(jnp.float32)
-    if laplacian:
-        deg = jnp.zeros(n, jnp.float32).at[u].add(w).at[v].add(w)
-        scale = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
-        w = w * scale[u] * scale[v]
-    Wv = make_w(Y, K)
-
-    s_local = u.shape[0] // p
-    cap = int(np.ceil(2 * s_local / p * capacity_factor)) + 8
-
-    espec = P(AXIS)
-    rspec = P()
-    if mode == "replicated":
-        body = functools.partial(_body_replicated, K=K, n=n)
-        out_z = P()
-    elif mode == "reduce_scatter":
-        assert n % p == 0, (n, p)
-        body = functools.partial(_body_reduce_scatter, K=K, n=n, p=p)
-        out_z = P(AXIS, None)
-    elif mode == "a2a":
-        assert n % p == 0, (n, p)
-        body = functools.partial(_body_a2a, K=K, n=n, p=p, cap=cap)
-        out_z = P(AXIS, None)
-    elif mode == "ring":
-        assert n % p == 0, (n, p)
-        body = functools.partial(_body_ring, K=K, n=n, p=p, cap=cap)
-        out_z = P(AXIS, None)
-    else:
+    if mode not in SCATTER_MODES + ("a2a", "ring"):
         raise ValueError(f"unknown mode {mode!r}")
+    if mode != "replicated":
+        assert n % p == 0, (n, p)
+    cap = bucket_cap(mode, u.shape[0] // p, p, capacity_factor)
+    return _program(mode, K, n, mesh, cap, laplacian)(u, v, w, Y, class_w)
 
+
+@functools.lru_cache(maxsize=16)
+def _program(mode: str, K: int, n: int, mesh: Mesh, cap: int,
+             laplacian: bool):
+    """`gee_sharded`'s shard_map-ped body under `jax.jit`, built once
+    per key: every later embed of the same shapes dispatches the one
+    cached executable."""
+    p = mesh.shape[AXIS]
+    body, out_z = {
+        "replicated": (functools.partial(_body_replicated, K=K, n=n),
+                       P()),
+        "reduce_scatter": (functools.partial(_body_reduce_scatter, K=K,
+                                             n=n, p=p), P(AXIS, None)),
+        "a2a": (functools.partial(_body_a2a, K=K, n=n, p=p, cap=cap),
+                P(AXIS, None)),
+        "ring": (functools.partial(_body_ring, K=K, n=n, p=p, cap=cap),
+                 P(AXIS, None)),
+    }[mode]
     fn = shard_map(body, mesh,
-                   in_specs=(espec, espec, espec, rspec, rspec),
+                   in_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P()),
                    out_specs=(out_z, P()))
-    return fn(u, v, w, Y, Wv)
+
+    def run(u, v, w, Y, class_w):
+        w = w.astype(jnp.float32)
+        if laplacian:
+            deg = jnp.zeros(n, jnp.float32).at[u].add(w).at[v].add(w)
+            scale = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
+            w = w * scale[u] * scale[v]
+        return fn(u, v, w, Y, class_w)
+    return jax.jit(run)
 
 
 def exact_capacity_factor(graph, p: int) -> float:
@@ -303,8 +387,9 @@ def gee_distributed(graph, Y, *, K: int, mode: str = "ring",
     g = graph.pad_to(s_pad)
     Y_pad = np.full(n_pad, -1, np.int32)
     Y_pad[:graph.n] = Y
+    Yj = jnp.asarray(Y_pad)
     Z, dropped = gee_sharded(
         jnp.asarray(g.u), jnp.asarray(g.v), jnp.asarray(g.w),
-        jnp.asarray(Y_pad), K=K, n=n_pad, mesh=mesh, mode=mode,
+        Yj, class_weights(Yj, K), K=K, n=n_pad, mesh=mesh, mode=mode,
         capacity_factor=capacity_factor, laplacian=laplacian)
     return np.asarray(Z)[:graph.n], int(dropped)

@@ -14,8 +14,9 @@ in where the scatter runs and how contributions move:
                   the out-of-core / serving-rebuild path.
   distributed:M   `core.distributed.gee_sharded` for M in
                   {replicated, reduce_scatter, a2a, ring} — SPMD
-                  collectives; the plan pads edges/rows to the mesh and
-                  measures the exact zero-drop capacity factor once.
+                  collectives; the plan pads edges/rows to the mesh,
+                  places the edges sharded over it and measures the
+                  exact zero-drop capacity factor once.
 
 Register new strategies with ``@register_backend("name")``; callers
 select them by name through ``Embedder(..., backend="name")`` without
@@ -153,8 +154,9 @@ class Backend:
         class_w: the (K,) class weights 1/n_k of the labels Yj
         (`core.gee.class_weights`).  A contribution's weight depends
         only on its class, which is its column of Z: pallas scales Z's
-        columns by class_w; the other backends weight each contribution
-        by the per-node `make_w(Yj, K, class_w)`."""
+        columns by class_w; the distributed backends weight each
+        contribution by its donor's class weight; the others by the
+        per-node `make_w(Yj, K, class_w)`."""
         raise NotImplementedError
 
     def slots(self, plan: Plan) -> Optional[int]:
@@ -410,13 +412,23 @@ class StreamingBackend(Backend):
 class DistributedBackend(Backend):
     """SPMD collectives over the edge mesh (`core.distributed`).
 
-    The plan pads edges and rows to the mesh, places the padded arrays,
-    and — for bucketed modes — measures the exact zero-drop capacity
-    factor from the owner histogram (an O(s) host pass now done once
-    instead of per fit).  The capacity factor depends on the device
-    count, so it is the persisted host artifact and the device count is
-    baked into the cache key (`cache_context`); padding and placement
-    are per-process finalize work.
+    The plan pads edges and rows to the mesh, places the padded arrays
+    sharded over the mesh's edge axis (span ``encoder.place``), and —
+    for bucketed modes — measures the exact zero-drop capacity factor
+    from the owner histogram (an O(s) host pass now done once instead
+    of per fit).  The capacity factor depends on the device count, so
+    it is the persisted host artifact and the device count is baked
+    into the cache key (`cache_context`); padding and placement are
+    per-process finalize work.
+
+    Each embed replicates the labels and the Embedder's class weights
+    over the mesh and dispatches the mode's one jitted program
+    (`gee_sharded`, span ``encoder.shard_embed``), which weights each
+    contribution by its donor's class weight.  It counts the least
+    bytes one chip must send in the mode's collective
+    (``repro_distributed_collective_bytes_total{mode}``,
+    `core.distributed.collective_bytes`; the lowered collective may
+    send more).
     """
 
     mode = "ring"
@@ -439,31 +451,54 @@ class DistributedBackend(Backend):
         return {"capacity_factor": cf if cf is not None else 2.0}
 
     def plan_finalize(self, p, graph, *, mesh=None):
-        from repro.core.distributed import pad_rows
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.core.distributed import (AXIS, bucket_cap,
+                                            collective_bytes, embed_slots,
+                                            pad_rows)
         mesh = self._mesh(mesh)
         nd = mesh.devices.size
         n_pad = pad_rows(graph.n, nd)
         s_pad = pad_rows(graph.s, nd)
         g = Graph(np.asarray(graph.u), np.asarray(graph.v), p.w_eff,
                   graph.n).pad_to(s_pad)
-        p.data = {"mesh": mesh, "n_pad": n_pad,
-                  "capacity_factor": float(p.host["capacity_factor"]),
-                  "u": jnp.asarray(g.u), "v": jnp.asarray(g.v),
-                  "w": jnp.asarray(g.w)}
+        cf = float(p.host["capacity_factor"])
+        cap = bucket_cap(self.mode, s_pad // nd, nd, cf)
+        # straight from host memory: each chip receives only its s/p
+        # edges, and no embed moves them again
+        with obs.span("encoder.place", backend=self.name,
+                      s=s_pad) as sp:
+            u, v, w = sp.fence(jax.device_put(
+                (g.u, g.v, g.w), NamedSharding(mesh, PartitionSpec(AXIS))))
+        p.data = {"mesh": mesh, "n_pad": n_pad, "capacity_factor": cf,
+                  "u": u, "v": v, "w": w,
+                  "slots": embed_slots(self.mode, s_pad, nd, cap),
+                  "collective_bytes": collective_bytes(
+                      self.mode, n_pad, p.config.K, nd, cap)}
 
     def slots(self, plan):
-        return None            # capacity padding differs by mode
+        return plan.data["slots"]
 
     def embed(self, plan, Yj, class_w):
-        from repro.core.distributed import gee_sharded
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.core.distributed import SCATTER_MODES, gee_sharded
         d, cfg = plan.data, plan.config
-        Y_pad = jnp.concatenate([
-            Yj, jnp.full(d["n_pad"] - plan.n, -1, jnp.int32)])
-        Z, dropped = gee_sharded(
-            d["u"], d["v"], d["w"], Y_pad, K=cfg.K, n=d["n_pad"],
-            mesh=d["mesh"], mode=self.mode,
-            capacity_factor=d["capacity_factor"])
-        return Z[:plan.n], {"dropped": int(dropped)}
+        pad = d["n_pad"] - plan.n
+        if pad:
+            Yj = jnp.concatenate([Yj, jnp.full(pad, -1, jnp.int32)])
+        Yj, class_w = jax.device_put(
+            (Yj, class_w), NamedSharding(d["mesh"], PartitionSpec()))
+        with obs.span("encoder.shard_embed", backend=self.name):
+            Z, dropped = gee_sharded(
+                d["u"], d["v"], d["w"], Yj, class_w, K=cfg.K, n=d["n_pad"],
+                mesh=d["mesh"], mode=self.mode,
+                capacity_factor=d["capacity_factor"])
+        obs.counter("repro_distributed_collective_bytes_total",
+                    d["collective_bytes"], mode=self.mode)
+        # the scatter modes drop nothing: no read back, so the host runs
+        # on while the device works
+        info = {"dropped": (0 if self.mode in SCATTER_MODES
+                            else int(dropped))}
+        return (Z[:plan.n] if pad else Z), info
 
 
 for _mode in ("replicated", "reduce_scatter", "a2a", "ring"):

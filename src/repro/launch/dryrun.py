@@ -244,6 +244,7 @@ def run_cell(arch, shape_name, *, multi_pod=False, impl="flash",
 def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
             s=1_800_000_000, K=50, save=True):
     from repro.core.distributed import AXIS, gee_a2a_steady, gee_sharded
+    from repro.core.gee import class_weights
     mesh = make_gee_mesh(multi_pod=multi_pod)
     p = mesh.shape[AXIS]
     n_pad = ((n + p - 1) // p) * p
@@ -271,8 +272,8 @@ def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
         lowered = jax.jit(fn).lower(bi, bi, bf, Y)
     else:
         def fn(u, v, w, Y):
-            Z, dropped = gee_sharded(u, v, w, Y, K=K, n=n_pad, mesh=mesh,
-                                     mode=mode)
+            Z, dropped = gee_sharded(u, v, w, Y, class_weights(Y, K), K=K,
+                                     n=n_pad, mesh=mesh, mode=mode)
             return Z, dropped
 
         lowered = jax.jit(fn).lower(u, u, w, Y)

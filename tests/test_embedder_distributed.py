@@ -1,0 +1,208 @@
+"""The Embedder's four-device path on 4 host devices (subprocess so the
+device-count flag never leaks into other tests): `auto` resolves to
+`distributed:reduce_scatter`, the plan places its edges sharded over the
+mesh, every refit dispatches one cached program, the work and collective
+counters equal hand arithmetic, and Z matches the serial oracle."""
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+P_DEV = 4
+K = 7
+
+SCRIPT = r"""
+import json
+import numpy as np, jax
+from repro import obs
+from repro.core import ref_python as R
+from repro.core.distributed import bucket_cap, edge_mesh, gee_distributed
+from repro.encoder import Embedder, EncoderConfig
+from repro.graph.edges import make_labels
+from repro.graph.generators import erdos_renyi
+
+K, p = %(K)d, %(p)d
+obs.configure(enabled=True)
+r = obs.registry()
+out = {"devices": len(jax.devices())}
+
+
+def counts(backend, mode):
+    return [r.counter_value("repro_kernel_slots_total", backend=backend),
+            r.counter_value("repro_kernel_contributions_total",
+                            backend=backend, donor="labeled"),
+            r.counter_value("repro_kernel_contributions_total",
+                            backend=backend, donor="unlabeled"),
+            r.counter_value("repro_distributed_collective_bytes_total",
+                            mode=mode)]
+
+
+# auto on n = 1003, s = 20007 (both padded to the mesh): a fit, two refits
+g = erdos_renyi(1003, 20007, seed=1, weighted=True)
+rng = np.random.default_rng(0)
+Ys = [make_labels(g.n, K, f, rng) for f in (0.2, 0.3, 0.1)]
+emb = Embedder(EncoderConfig(K=K), plan_cache=None)
+errs, compiles, cnt = [], [], []
+for i, Y in enumerate(Ys):
+    c0 = r.counter_value("repro_jax_compiles_total")
+    emb.fit(g, Y) if i == 0 else emb.refit(Y)
+    Z = emb.transform()
+    compiles.append(r.counter_value("repro_jax_compiles_total") - c0)
+    errs.append(float(np.abs(Z - R.gee_numpy(g.u, g.v, g.w, Y, K, g.n)).max()))
+    cnt.append(counts(emb.backend.name, "reduce_scatter"))
+d = emb._plan.data
+out["auto"] = {
+    "backend": emb.backend.name, "errs": errs, "compiles": compiles,
+    "counts": cnt, "Z_shape": list(emb.Z_.shape),
+    "labeled": [int((Y[g.u] >= 0).sum() + (Y[g.v] >= 0).sum()) for Y in Ys],
+    "placed": {k: {"spec": str(d[k].sharding.spec),
+                   "shards": sorted(s.data.shape[0]
+                                    for s in d[k].addressable_shards),
+                   "devices": len({s.device for s in d[k].addressable_shards})}
+               for k in ("u", "v", "w")},
+    "dropped": emb.last_info_["dropped"],
+    "spans": [e["name"] for e in obs.trace_events()]}
+
+# the class weights read by each donor's label give the Z that the
+# per-node weights make_w(Y, K) gathered per edge give, bit for bit
+from jax.sharding import PartitionSpec as P
+from repro.core import distributed as D
+from repro.core.gee import edge_contributions, make_w
+
+
+def per_node_weights(u, v, w, Y, Wv):
+    Z = D._scatter_rows(1004, K, *edge_contributions(u, v, w, Y, Wv))
+    return jax.lax.psum_scatter(Z, D.AXIS, scatter_dimension=0, tiled=True)
+
+
+mesh = edge_mesh()
+old = jax.jit(D.shard_map(per_node_weights, mesh,
+                          in_specs=(P(D.AXIS),) * 3 + (P(), P()),
+                          out_specs=P(D.AXIS, None)))
+Y_pad = jax.numpy.asarray(np.concatenate([Ys[-1], [-1]]).astype(np.int32))
+Z_old = np.asarray(old(d["u"], d["v"], d["w"], Y_pad, make_w(Y_pad, K)))
+out["auto"]["same_as_make_w"] = bool(np.array_equal(emb.transform(),
+                                                    Z_old[:g.n]))
+Zg, _ = gee_distributed(g, Ys[-1], K=K, mode="reduce_scatter", mesh=mesh)
+out["auto"]["same_as_gee_distributed"] = bool(
+    np.array_equal(emb.transform(), Zg))
+
+# n and s divisible by the mesh: Z stays row-sharded, no slice
+g2 = erdos_renyi(1000, 20000, seed=2, weighted=True)
+emb2 = Embedder(EncoderConfig(K=K), plan_cache=None).fit(g2, Ys[0][:1000])
+out["unpadded"] = {
+    "spec": str(emb2.Z_.sharding.spec),
+    "rows": sorted(s.data.shape[0] for s in emb2.Z_.addressable_shards),
+    "err": float(np.abs(emb2.transform() - R.gee_numpy(
+        g2.u, g2.v, g2.w, Ys[0][:1000], K, g2.n)).max())}
+
+# every mode through the Embedder: Z, and one embed's counters
+for mode in ("replicated", "reduce_scatter", "a2a", "ring"):
+    name = "distributed:" + mode
+    before = counts(name, mode)
+    e = Embedder(EncoderConfig(K=K), backend=name, plan_cache=None)
+    e.fit(g, Ys[1])
+    out[mode] = {
+        "err": float(np.abs(e.transform() - R.gee_numpy(
+            g.u, g.v, g.w, Ys[1], K, g.n)).max()),
+        "dropped": e.last_info_["dropped"],
+        "cap": bucket_cap(mode, 20008 // p, p,
+                          e._plan.data["capacity_factor"]),
+        "delta": [a - b for a, b in zip(counts(name, mode), before)]}
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def res():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={P_DEV}",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    r = subprocess.run([sys.executable, "-c",
+                        SCRIPT % {"K": K, "p": P_DEV}],
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT ")]
+    return json.loads(line[-1][len("RESULT "):])
+
+
+S, S_PAD, N_PAD = 20007, 20008, 1004
+
+
+def test_runs_on_4_devices(res):
+    assert res["devices"] == P_DEV
+
+
+def test_auto_resolves_to_reduce_scatter(res):
+    assert res["auto"]["backend"] == "distributed:reduce_scatter"
+
+
+@pytest.mark.parametrize("step", [0, 1, 2], ids=["fit", "refit", "refit2"])
+def test_fit_and_refits_match_oracle(res, step):
+    assert res["auto"]["errs"][step] < 1e-4
+    assert res["auto"]["Z_shape"] == [1003, K]
+    assert res["auto"]["dropped"] == 0
+
+
+@pytest.mark.parametrize("arr", ["u", "v", "w"])
+def test_plan_edges_sharded_a_quarter_per_device(res, arr):
+    placed = res["auto"]["placed"][arr]
+    assert placed["spec"] == "PartitionSpec('edges',)"
+    assert placed["devices"] == P_DEV
+    assert placed["shards"] == [S_PAD // P_DEV] * P_DEV
+
+
+def test_second_refit_compiles_nothing(res):
+    assert res["auto"]["compiles"][2] == 0
+
+
+@pytest.mark.parametrize("step", [0, 1, 2], ids=["fit", "refit", "refit2"])
+def test_counters_by_hand(res, step):
+    """Per embed: 2 s_pad slots; the 2 s real contributions split by
+    the donor's label; (p-1)/p of the (n_pad, K) f32 accumulator sent."""
+    labeled = sum(res["auto"]["labeled"][:step + 1])
+    k = step + 1
+    assert res["auto"]["counts"][step] == [
+        k * 2 * S_PAD, labeled, k * 2 * S - labeled,
+        k * (P_DEV - 1) * N_PAD * K * 4 // P_DEV]
+
+
+def test_spans(res):
+    spans = res["auto"]["spans"]
+    assert spans.count("encoder.place") == 1          # one plan
+    assert spans.count("encoder.shard_embed") == 3    # fit, two refits
+
+
+def test_class_weights_give_make_w_z(res):
+    """Each donor's weight read from the class weights by its label is
+    the per-node weight `make_w` gathers per edge: the same Z, bit for
+    bit, through the Embedder and through `gee_distributed`."""
+    assert res["auto"]["same_as_make_w"]
+    assert res["auto"]["same_as_gee_distributed"]
+
+
+def test_unpadded_z_stays_row_sharded(res):
+    u = res["unpadded"]
+    assert u["spec"] == "PartitionSpec('edges',)"
+    assert u["rows"] == [1000 // P_DEV] * P_DEV
+    assert u["err"] < 1e-4
+
+
+@pytest.mark.parametrize("mode",
+                         ["replicated", "reduce_scatter", "a2a", "ring"])
+def test_mode_counts_by_hand(res, mode):
+    m = res[mode]
+    assert m["err"] < 1e-4 and m["dropped"] == 0
+    cap, z = m["cap"], N_PAD * K * 4
+    slots = 2 * S_PAD if mode in ("replicated", "reduce_scatter") \
+        else P_DEV * P_DEV * cap
+    sent = {"replicated": 2 * (P_DEV - 1) * z // P_DEV,
+            "reduce_scatter": (P_DEV - 1) * z // P_DEV,
+            "a2a": (P_DEV - 1) * cap * 12,
+            "ring": (P_DEV - 1) * z // P_DEV}[mode]
+    assert m["delta"][0] == slots
+    assert m["delta"][1] + m["delta"][2] == 2 * S
+    assert m["delta"][3] == sent
