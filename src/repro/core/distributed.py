@@ -5,14 +5,18 @@ The paper's Ligra implementation parallelizes the edge loop across cores
 that share one coherent DRAM array Z, racing on Z[u, k] and resolving
 races with lock-free atomic adds.  On a TPU pod there is no shared
 mutable HBM, so "who owns Z" becomes an explicit design axis.  Four
-reduction modes, all computing bit-identical Z:
+reduction modes, all computing Z to float32 rounding:
 
-  replicated      every chip: local scatter-add into a full (n, K) Z,
-                  then all-reduce (psum).  Direct analog of the paper's
-                  shared array.  Memory O(n*K) per chip.
-  reduce_scatter  same local pass, but psum_scatter leaves each chip
-                  with its own row shard.  Memory O(n*K) transient,
-                  O(n*K/P) resident; collective cost = 1 reduce-scatter.
+  replicated      every chip: local XLA scatter-add into a full (n, K)
+                  Z, then all-reduce (psum).  Direct analog of the
+                  paper's shared array.  Memory O(n*K) per chip.
+  reduce_scatter  every chip runs the destination-tiled scatter kernel
+                  (`kernels.gee_scatter`) over its own contributions,
+                  packed once on the chips (`pack_sharded`), into a full
+                  (n, K) Z; psum_scatter leaves each chip its own row
+                  shard, whose columns it scales by the class weights.
+                  Memory O(n*K) transient, O(n*K/P) resident;
+                  collective cost = 1 reduce-scatter.
   a2a             contributions bucketed by destination row-shard
                   (sort + capacity-padded pack, exactly like an MoE
                   dispatch), exchanged with one all_to_all, then local
@@ -24,11 +28,14 @@ reduction modes, all computing bit-identical Z:
                   TPU-native replacement for atomics: deterministic
                   neighbor exchanges instead of racing writes.
 
-Each mode is one jitted program, built once per (mode, K, n, mesh,
-capacity, laplacian) and cached (`_program`), so a refit dispatches one
-executable.  The edges arrive sharded over the mesh's edge axis; the
-labels and the (K,) class weights 1/n_k arrive replicated, and each
-contribution takes its donor's class weight by the donor's label.
+Each mode's embed is one jitted program, built once per key and cached
+(`_program`, `_packed_program`), so a refit dispatches one executable.
+The edges (or, in reduce_scatter, the packed slots) arrive sharded over
+the mesh's edge axis; the labels and the (K,) class weights 1/n_k
+arrive replicated.  replicated, a2a and ring weight each contribution
+by its donor's class weight, read by the donor's label; reduce_scatter
+scatters the edge weight under the donor's class and scales column k
+by 1/n_k once, after the reduction.
 
 Bucketed modes use capacity padding (cap = mean * capacity_factor).
 With randomly-shuffled edges, bucket sizes concentrate tightly around
@@ -46,10 +53,10 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.gee import class_weights
+from repro.kernels.gee_scatter import EDGE_BLOCK, TILE_N, gee_scatter_pallas
 
 AXIS = "edges"
-#: modes that scatter every padded edge's two contributions in place,
-#: with no capacity padding
+#: modes that scatter every contribution, with no capacity padding
 SCATTER_MODES = ("replicated", "reduce_scatter")
 
 
@@ -135,11 +142,23 @@ def _body_replicated(u, v, w, Y, class_w, *, K, n):
     return jax.lax.psum(Z, AXIS), jnp.zeros((), jnp.int32)
 
 
-def _body_reduce_scatter(u, v, w, Y, class_w, *, K, n, p):
-    dst, cls, val = _contributions(u, v, w, Y, class_w)
-    Z = _scatter_rows(n, K, dst, cls, val)
-    Zs = jax.lax.psum_scatter(Z, AXIS, scatter_dimension=0, tiled=True)
-    return Zs, jnp.zeros((), jnp.int32)
+def _body_reduce_scatter(rows, src, w, Y, class_w, *, K, n, tile_n,
+                         interpret):
+    """One chip's (T, BPT, 1, EB) packed slots through the scatter
+    kernel, each slot under its donor's current class (an unlabeled
+    donor's -1 matches no column); the (n, K) partial Z reduced over the
+    chips, and each chip's row shard scaled by the class weights."""
+    Z = gee_scatter_pallas(rows, Y[src], w, num_tiles=rows.shape[0],
+                           tile_n=tile_n, kdim=-(-K // 8) * 8,
+                           interpret=interpret)
+    # reduced flat (a chip's rows are a contiguous run of the row-major
+    # Z): on TPU v5e this runs as one all-reduce of the n*K floats and
+    # a slice.  Scattered along the rows of (n, K), it becomes a fusion
+    # over a layout whose K columns fill 128 lanes, 4.5 ms a step slower
+    # at n = 3M, K = 50 on four chips.
+    Zs = jax.lax.psum_scatter(Z[:n, :K].reshape(-1), AXIS,
+                              scatter_dimension=0, tiled=True)
+    return Zs.reshape(-1, K) * class_w
 
 
 def _body_a2a(u, v, w, Y, class_w, *, K, n, p, cap):
@@ -256,6 +275,111 @@ def _body_ring(u, v, w, Y, class_w, *, K, n, p, cap):
 
 
 # ---------------------------------------------------------------------------
+# reduce_scatter: each chip's contributions packed on the chips
+# ---------------------------------------------------------------------------
+
+
+def _edge_weights(u, v, w, n: int, laplacian: bool):
+    """Float32 edge weights, Laplacian-scaled w / sqrt(deg_u deg_v)
+    where asked (degrees over the whole padded edge list)."""
+    w = w.astype(jnp.float32)
+    if laplacian:
+        deg = jnp.zeros(n, jnp.float32).at[u].add(w).at[v].add(w)
+        scale = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
+        w = w * scale[u] * scale[v]
+    return w
+
+
+def _sort_body(u, v, w, *, tile_n, T):
+    """One chip's contributions (dst, src, w) = ([u, v], [v, u], [w, w])
+    stable-sorted by destination tile in one multi-operand sort, as
+    (tile-local row, src, w); each tile's first index and count; and
+    the fullest tile's count over all chips."""
+    dst = jnp.concatenate([u, v])
+    tile, dst, src, w = jax.lax.sort(
+        (dst // tile_n, dst, jnp.concatenate([v, u]),
+         jnp.concatenate([w, w])), num_keys=1, is_stable=True)
+    bounds = jnp.searchsorted(tile, jnp.arange(T + 1, dtype=tile.dtype))
+    counts = bounds[1:] - bounds[:-1]
+    return (dst - tile * tile_n, src, w, bounds[:-1], counts,
+            jax.lax.pmax(jnp.max(counts), AXIS))
+
+
+def _slot_body(row, src, w, starts, counts, *, bpt, eb):
+    """The sorted contributions copied to their (T, bpt, 1, eb) slots:
+    tile t's run of counts[t] contributions from starts[t] fills its
+    blocks in order, one contiguous window a tile; the rest stay 0
+    (w = 0 adds nothing)."""
+    width = bpt * eb
+    used = jnp.arange(width) < counts[:, None]
+
+    def put(x):
+        x = jnp.concatenate([x, jnp.zeros(width, x.dtype)])
+        runs = jax.vmap(
+            lambda a: jax.lax.dynamic_slice(x, (a,), (width,)))(starts)
+        return jnp.where(used, runs, 0).reshape(-1, bpt, 1, eb)
+    return put(row), put(src), put(w)
+
+
+@functools.lru_cache(maxsize=16)
+def _sort_program(n: int, mesh: Mesh, tile_n: int, laplacian: bool):
+    body = functools.partial(_sort_body, tile_n=tile_n,
+                             T=-(-n // tile_n))
+    fn = shard_map(body, mesh, in_specs=(P(AXIS),) * 3,
+                   out_specs=(P(AXIS),) * 5 + (P(),))
+
+    def sort(u, v, w):
+        return fn(u, v, _edge_weights(u, v, w, n, laplacian))
+    return jax.jit(sort)
+
+
+@functools.lru_cache(maxsize=16)
+def _slot_program(mesh: Mesh, eb: int, bpt: int):
+    body = functools.partial(_slot_body, bpt=bpt, eb=eb)
+    return jax.jit(shard_map(body, mesh, in_specs=(P(AXIS),) * 5,
+                             out_specs=(P(AXIS),) * 3))
+
+
+def pack_sharded(u, v, w, *, n: int, mesh: Mesh, tile_n: int = TILE_N,
+                 edge_block: int = EDGE_BLOCK, laplacian: bool = False):
+    """Pack each chip's contributions, on the chips, into the scatter
+    kernel's destination-tiled layout (`kernels.ops.pack_edges`).
+
+    u, v, w: (s,) edges sharded over the mesh's edge axis, s divisible
+    by the mesh size.  Chip i's contributions are ([u_i, v_i], [v_i,
+    u_i], [w_i, w_i]) (destination, label donor, weight), over n rows in
+    T = ceil(n / tile_n) tiles.  Returns (rows, src, w), each (p * T,
+    BPT, 1, edge_block) and sharded over the edge axis: chip i's (T,
+    BPT, 1, edge_block) slab is `pack_edges` of its own contributions,
+    slot for slot, padded with zero blocks to BPT, the block count of
+    the fullest tile over all chips.  That count is the one value read
+    back to the host: it fixes the static shape."""
+    *runs, fullest = _sort_program(n, mesh, tile_n, laplacian)(u, v, w)
+    bpt = max(1, -(-int(fullest) // edge_block))
+    return _slot_program(mesh, edge_block, bpt)(*runs)
+
+
+def gee_packed(rows, src, w, Y, class_w, *, K: int, n: int, mesh: Mesh,
+               tile_n: int = TILE_N, interpret="auto"):
+    """The reduce_scatter embed over `pack_sharded`'s slots: Z (n, K)
+    row-sharded over the mesh.  Y: (n,) labels and class_w: (K,) class
+    weights 1/n_k of Y, both replicated."""
+    return _packed_program(K, n, mesh, tile_n, interpret)(
+        rows, src, w, Y, class_w)
+
+
+@functools.lru_cache(maxsize=16)
+def _packed_program(K: int, n: int, mesh: Mesh, tile_n: int, interpret):
+    """`gee_packed`'s shard_map-ped body under `jax.jit`, built once
+    per key."""
+    body = functools.partial(_body_reduce_scatter, K=K, n=n,
+                             tile_n=tile_n, interpret=interpret)
+    return jax.jit(shard_map(body, mesh,
+                             in_specs=(P(AXIS),) * 3 + (P(), P()),
+                             out_specs=P(AXIS, None)))
+
+
+# ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
 
@@ -272,9 +396,12 @@ def bucket_cap(mode: str, s_local: int, p: int,
 
 def embed_slots(mode: str, s_pad: int, p: int, cap: int) -> int:
     """Contribution slots all chips scatter in one embed, padding
-    included: the 2 * s_pad contributions of the padded edges in the
-    scatter modes, p * p buckets of `cap` in the bucketed ones."""
-    return 2 * s_pad if mode in SCATTER_MODES else p * p * cap
+    included, in the modes that scatter with XLA: the 2 * s_pad
+    contributions of the padded edges in replicated, p * p buckets of
+    `cap` in the bucketed ones.  (reduce_scatter runs the kernel over
+    its packed slots, whose count is their size.)"""
+    return {"replicated": 2 * s_pad, "a2a": p * p * cap,
+            "ring": p * p * cap}[mode]
 
 
 def collective_bytes(mode: str, n_pad: int, K: int, p: int, cap: int
@@ -305,14 +432,16 @@ def gee_sharded(u, v, w, Y, class_w, *, K: int, n: int, mesh: Mesh,
                 mode: str = "ring", capacity_factor: float = 2.0,
                 laplacian: bool = False):
     """Distributed GEE under shard_map, one jitted program per (mode,
-    K, n, mesh, bucket capacity, laplacian) (`_program`).
+    K, n, mesh, bucket capacity, laplacian) (`_program`); reduce_scatter
+    packs the edges on the chips first (`pack_sharded`, with one read
+    back) and runs `gee_packed`.
 
     u, v, w: (s,) edge arrays, s divisible by mesh size (pad first —
     `Graph.pad_to`); sharded over the mesh's edge axis, each chip holds
     its s/p.  Y: (n_pad,) labels, n divisible by mesh size for
     row-sharded modes; class_w: (K,) class weights 1/n_k of Y
-    (`core.gee.class_weights`), which weight each contribution by its
-    donor's class.  Returns (Z, dropped):
+    (`core.gee.class_weights`): class k's contributions weigh 1/n_k.
+    Returns (Z, dropped):
       replicated          -> Z (n, K) replicated
       others              -> Z (n, K) row-sharded over the mesh
     """
@@ -322,6 +451,10 @@ def gee_sharded(u, v, w, Y, class_w, *, K: int, n: int, mesh: Mesh,
         raise ValueError(f"unknown mode {mode!r}")
     if mode != "replicated":
         assert n % p == 0, (n, p)
+    if mode == "reduce_scatter":
+        packed = pack_sharded(u, v, w, n=n, mesh=mesh, laplacian=laplacian)
+        return (gee_packed(*packed, Y, class_w, K=K, n=n, mesh=mesh),
+                jnp.zeros((), jnp.int32))
     cap = bucket_cap(mode, u.shape[0] // p, p, capacity_factor)
     return _program(mode, K, n, mesh, cap, laplacian)(u, v, w, Y, class_w)
 
@@ -336,8 +469,6 @@ def _program(mode: str, K: int, n: int, mesh: Mesh, cap: int,
     body, out_z = {
         "replicated": (functools.partial(_body_replicated, K=K, n=n),
                        P()),
-        "reduce_scatter": (functools.partial(_body_reduce_scatter, K=K,
-                                             n=n, p=p), P(AXIS, None)),
         "a2a": (functools.partial(_body_a2a, K=K, n=n, p=p, cap=cap),
                 P(AXIS, None)),
         "ring": (functools.partial(_body_ring, K=K, n=n, p=p, cap=cap),
@@ -348,12 +479,7 @@ def _program(mode: str, K: int, n: int, mesh: Mesh, cap: int,
                    out_specs=(out_z, P()))
 
     def run(u, v, w, Y, class_w):
-        w = w.astype(jnp.float32)
-        if laplacian:
-            deg = jnp.zeros(n, jnp.float32).at[u].add(w).at[v].add(w)
-            scale = jax.lax.rsqrt(jnp.maximum(deg, 1.0))
-            w = w * scale[u] * scale[v]
-        return fn(u, v, w, Y, class_w)
+        return fn(u, v, _edge_weights(u, v, w, n, laplacian), Y, class_w)
     return jax.jit(run)
 
 

@@ -12,11 +12,13 @@ in where the scatter runs and how contributions move:
                   changes re-resolve on device and never re-pack.
   streaming       chunked accumulate (O(chunk) device memory) —
                   the out-of-core / serving-rebuild path.
-  distributed:M   `core.distributed.gee_sharded` for M in
+  distributed:M   `core.distributed` for M in
                   {replicated, reduce_scatter, a2a, ring} — SPMD
                   collectives; the plan pads edges/rows to the mesh,
                   places the edges sharded over it and measures the
-                  exact zero-drop capacity factor once.
+                  exact zero-drop capacity factor once; reduce_scatter
+                  packs each chip's edges on the chips for the pallas
+                  kernel once per plan.
 
 Register new strategies with ``@register_backend("name")``; callers
 select them by name through ``Embedder(..., backend="name")`` without
@@ -153,10 +155,11 @@ class Backend:
 
         class_w: the (K,) class weights 1/n_k of the labels Yj
         (`core.gee.class_weights`).  A contribution's weight depends
-        only on its class, which is its column of Z: pallas scales Z's
-        columns by class_w; the distributed backends weight each
-        contribution by its donor's class weight; the others by the
-        per-node `make_w(Yj, K, class_w)`."""
+        only on its class, which is its column of Z: pallas and
+        distributed:reduce_scatter scale Z's columns by class_w; the
+        other distributed backends weight each contribution by its
+        donor's class weight; the rest by the per-node
+        `make_w(Yj, K, class_w)`."""
         raise NotImplementedError
 
     def slots(self, plan: Plan) -> Optional[int]:
@@ -421,11 +424,22 @@ class DistributedBackend(Backend):
     into the cache key (`cache_context`); padding and placement are
     per-process finalize work.
 
+    reduce_scatter then packs, inside the same span, each chip's
+    contributions on the chips into the pallas kernel's (T, BPT, 1, EB)
+    destination-tiled slots (`pack_sharded`: one sort, one read-back of
+    the fullest tile, one scatter into the slots) and keeps only the
+    packed slots: like the pallas backend's, they are label-free, so
+    no refit packs again.  Its embed gathers each slot's label, runs
+    the kernel on every chip into a full (n_pad, K) partial Z, reduces
+    it with one psum_scatter and scales each row shard's columns by the
+    class weights (`gee_packed`).  The other modes weight each
+    contribution by its donor's class weight and scatter with XLA
+    (`gee_sharded`).
+
     Each embed replicates the labels and the Embedder's class weights
-    over the mesh and dispatches the mode's one jitted program
-    (`gee_sharded`, span ``encoder.shard_embed``), which weights each
-    contribution by its donor's class weight.  It counts the least
-    bytes one chip must send in the mode's collective
+    over the mesh and dispatches the mode's one jitted program (span
+    ``encoder.shard_embed``).  It counts the least bytes one chip must
+    send in the mode's collective
     (``repro_distributed_collective_bytes_total{mode}``,
     `core.distributed.collective_bytes`; the lowered collective may
     send more).
@@ -454,7 +468,9 @@ class DistributedBackend(Backend):
         from jax.sharding import NamedSharding, PartitionSpec
         from repro.core.distributed import (AXIS, bucket_cap,
                                             collective_bytes, embed_slots,
-                                            pad_rows)
+                                            pack_sharded, pad_rows)
+        from repro.kernels.gee_scatter import resolve_interpret
+        cfg = p.config
         mesh = self._mesh(mesh)
         nd = mesh.devices.size
         n_pad = pad_rows(graph.n, nd)
@@ -463,24 +479,36 @@ class DistributedBackend(Backend):
                   graph.n).pad_to(s_pad)
         cf = float(p.host["capacity_factor"])
         cap = bucket_cap(self.mode, s_pad // nd, nd, cf)
+        packed = self.mode == "reduce_scatter"
         # straight from host memory: each chip receives only its s/p
         # edges, and no embed moves them again
         with obs.span("encoder.place", backend=self.name,
                       s=s_pad) as sp:
-            u, v, w = sp.fence(jax.device_put(
-                (g.u, g.v, g.w), NamedSharding(mesh, PartitionSpec(AXIS))))
+            edges = jax.device_put(
+                (g.u, g.v, g.w), NamedSharding(mesh, PartitionSpec(AXIS)))
+            if packed:
+                edges = pack_sharded(*edges, n=n_pad, mesh=mesh,
+                                     tile_n=cfg.tile_n,
+                                     edge_block=cfg.edge_block)
+            edges = sp.fence(edges)
         p.data = {"mesh": mesh, "n_pad": n_pad, "capacity_factor": cf,
-                  "u": u, "v": v, "w": w,
-                  "slots": embed_slots(self.mode, s_pad, nd, cap),
                   "collective_bytes": collective_bytes(
-                      self.mode, n_pad, p.config.K, nd, cap)}
+                      self.mode, n_pad, cfg.K, nd, cap)}
+        if packed:
+            p.data.update(zip(("rows", "src", "w"), edges),
+                          slots=int(edges[0].size),
+                          interpret=resolve_interpret(cfg.interpret))
+        else:
+            p.data.update(zip(("u", "v", "w"), edges),
+                          slots=embed_slots(self.mode, s_pad, nd, cap))
 
     def slots(self, plan):
         return plan.data["slots"]
 
     def embed(self, plan, Yj, class_w):
         from jax.sharding import NamedSharding, PartitionSpec
-        from repro.core.distributed import SCATTER_MODES, gee_sharded
+        from repro.core.distributed import (SCATTER_MODES, gee_packed,
+                                            gee_sharded)
         d, cfg = plan.data, plan.config
         pad = d["n_pad"] - plan.n
         if pad:
@@ -488,10 +516,17 @@ class DistributedBackend(Backend):
         Yj, class_w = jax.device_put(
             (Yj, class_w), NamedSharding(d["mesh"], PartitionSpec()))
         with obs.span("encoder.shard_embed", backend=self.name):
-            Z, dropped = gee_sharded(
-                d["u"], d["v"], d["w"], Yj, class_w, K=cfg.K, n=d["n_pad"],
-                mesh=d["mesh"], mode=self.mode,
-                capacity_factor=d["capacity_factor"])
+            if self.mode == "reduce_scatter":
+                Z = gee_packed(d["rows"], d["src"], d["w"], Yj, class_w,
+                               K=cfg.K, n=d["n_pad"], mesh=d["mesh"],
+                               tile_n=cfg.tile_n,
+                               interpret=d["interpret"])
+                dropped = 0
+            else:
+                Z, dropped = gee_sharded(
+                    d["u"], d["v"], d["w"], Yj, class_w, K=cfg.K,
+                    n=d["n_pad"], mesh=d["mesh"], mode=self.mode,
+                    capacity_factor=d["capacity_factor"])
         obs.counter("repro_distributed_collective_bytes_total",
                     d["collective_bytes"], mode=self.mode)
         # the scatter modes drop nothing: no read back, so the host runs

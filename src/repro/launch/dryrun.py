@@ -243,8 +243,10 @@ def run_cell(arch, shape_name, *, multi_pod=False, impl="flash",
 
 def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
             s=1_800_000_000, K=50, save=True):
-    from repro.core.distributed import AXIS, gee_a2a_steady, gee_sharded
+    from repro.core.distributed import (AXIS, gee_a2a_steady, gee_packed,
+                                        gee_sharded)
     from repro.core.gee import class_weights
+    from repro.kernels.gee_scatter import EDGE_BLOCK, TILE_N
     mesh = make_gee_mesh(multi_pod=multi_pod)
     p = mesh.shape[AXIS]
     n_pad = ((n + p - 1) // p) * p
@@ -270,6 +272,22 @@ def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
                                   mesh=mesh)
 
         lowered = jax.jit(fn).lower(bi, bi, bf, Y)
+    elif mode == "reduce_scatter":
+        # the per-step program over each chip's packed (T, BPT, 1, EB)
+        # slots; packing runs once per plan and its fullest tile fixes
+        # BPT, which needs the graph: the dry run takes the mean tile's
+        T = -(-n_pad // TILE_N)
+        bpt = -(-2 * (s_pad // p) // (T * EDGE_BLOCK))
+        shape = (p * T, bpt, 1, EDGE_BLOCK)
+        si = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=espec)
+        sf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=espec)
+
+        def fn(rows, src, w, Y):
+            return (gee_packed(rows, src, w, Y, class_weights(Y, K), K=K,
+                               n=n_pad, mesh=mesh),
+                    jnp.zeros((), jnp.int32))
+
+        lowered = jax.jit(fn).lower(si, si, sf, Y)
     else:
         def fn(u, v, w, Y):
             Z, dropped = gee_sharded(u, v, w, Y, class_weights(Y, K), K=K,

@@ -1,8 +1,10 @@
 """The Embedder's four-device path on 4 host devices (subprocess so the
 device-count flag never leaks into other tests): `auto` resolves to
-`distributed:reduce_scatter`, the plan places its edges sharded over the
-mesh, every refit dispatches one cached program, the work and collective
-counters equal hand arithmetic, and Z matches the serial oracle."""
+`distributed:reduce_scatter`, the plan packs each device's edges on the
+devices into the scatter kernel's slots exactly as the host packer
+would, every refit dispatches one cached program, the work and
+collective counters equal hand arithmetic, and Z matches the serial
+oracle."""
 import json
 import os
 import subprocess
@@ -21,8 +23,10 @@ from repro import obs
 from repro.core import ref_python as R
 from repro.core.distributed import bucket_cap, edge_mesh, gee_distributed
 from repro.encoder import Embedder, EncoderConfig
-from repro.graph.edges import make_labels
+from repro.encoder.plan import effective_weights
+from repro.graph.edges import Graph, make_labels
 from repro.graph.generators import erdos_renyi
+from repro.kernels.ops import pack_edges
 
 K, p = %(K)d, %(p)d
 obs.configure(enabled=True)
@@ -59,15 +63,38 @@ out["auto"] = {
     "counts": cnt, "Z_shape": list(emb.Z_.shape),
     "labeled": [int((Y[g.u] >= 0).sum() + (Y[g.v] >= 0).sum()) for Y in Ys],
     "placed": {k: {"spec": str(d[k].sharding.spec),
+                   "shape": list(d[k].shape),
                    "shards": sorted(s.data.shape[0]
                                     for s in d[k].addressable_shards),
                    "devices": len({s.device for s in d[k].addressable_shards})}
-               for k in ("u", "v", "w")},
+               for k in ("rows", "src", "w")},
+    "edges_kept": sorted({"u", "v"} & set(d)),
     "dropped": emb.last_info_["dropped"],
     "spans": [e["name"] for e in obs.trace_events()]}
 
-# the class weights read by each donor's label give the Z that the
-# per-node weights make_w(Y, K) gathered per edge give, bit for bit
+# each device's packed slots are the host packer's for its own share of
+# the padded edges, padded with zero blocks to the fullest device's
+gp = g.pad_to(20008)
+per, T, bpt = 20008 // p, d["rows"].shape[0] // p, d["rows"].shape[1]
+same, host_bpt = [], []
+for i in range(p):
+    e = slice(i * per, (i + 1) * per)
+    host = pack_edges(np.concatenate([gp.u[e], gp.v[e]]),
+                      np.concatenate([gp.v[e], gp.u[e]]),
+                      np.concatenate([gp.w[e], gp.w[e]]), 1004)
+    host_bpt.append(host[0].shape[1])
+    for dev, ref in zip((d["rows"], d["src"], d["w"]), host[:3]):
+        mine = np.asarray(dev)[i * T:(i + 1) * T]
+        b = ref.shape[1]
+        same.append(host[3] == T and b <= bpt and
+                    bool(np.array_equal(mine[:, :b], ref)) and
+                    not mine[:, b:].any())
+out["auto"]["packed_as_host"] = same
+out["auto"]["host_bpt"] = host_bpt
+
+# the class weights read by each donor's label (replicated mode) give
+# the Z that the per-node weights make_w(Y, K) gathered per edge give,
+# bit for bit
 from jax.sharding import PartitionSpec as P
 from repro.core import distributed as D
 from repro.core.gee import edge_contributions, make_w
@@ -75,16 +102,19 @@ from repro.core.gee import edge_contributions, make_w
 
 def per_node_weights(u, v, w, Y, Wv):
     Z = D._scatter_rows(1004, K, *edge_contributions(u, v, w, Y, Wv))
-    return jax.lax.psum_scatter(Z, D.AXIS, scatter_dimension=0, tiled=True)
+    return jax.lax.psum(Z, D.AXIS)
 
 
 mesh = edge_mesh()
 old = jax.jit(D.shard_map(per_node_weights, mesh,
                           in_specs=(P(D.AXIS),) * 3 + (P(), P()),
-                          out_specs=P(D.AXIS, None)))
+                          out_specs=P()))
+rep = Embedder(EncoderConfig(K=K), backend="distributed:replicated",
+               plan_cache=None).fit(g, Ys[-1])
+dr = rep._plan.data
 Y_pad = jax.numpy.asarray(np.concatenate([Ys[-1], [-1]]).astype(np.int32))
-Z_old = np.asarray(old(d["u"], d["v"], d["w"], Y_pad, make_w(Y_pad, K)))
-out["auto"]["same_as_make_w"] = bool(np.array_equal(emb.transform(),
+Z_old = np.asarray(old(dr["u"], dr["v"], dr["w"], Y_pad, make_w(Y_pad, K)))
+out["auto"]["same_as_make_w"] = bool(np.array_equal(rep.transform(),
                                                     Z_old[:g.n]))
 Zg, _ = gee_distributed(g, Ys[-1], K=K, mode="reduce_scatter", mesh=mesh)
 out["auto"]["same_as_gee_distributed"] = bool(
@@ -99,6 +129,38 @@ out["unpadded"] = {
     "err": float(np.abs(emb2.transform() - R.gee_numpy(
         g2.u, g2.v, g2.w, Ys[0][:1000], K, g2.n)).max())}
 
+# reduce_scatter against the oracle: n = 1003 is a multiple of neither
+# the kernel's 256-row tile nor p; donors 20%% labeled; nodes 900.. are
+# isolated; 501 self-loops; the edge count pads to the mesh
+
+
+def rs_err(g, Y, *, laplacian=False, functional=False):
+    cfg = EncoderConfig(K=K, laplacian=laplacian)
+    Zref = R.gee_numpy(g.u, g.v, effective_weights(g, cfg), Y, K, g.n)
+    if functional:
+        Z, _ = gee_distributed(g, Y, K=K, mode="reduce_scatter", mesh=mesh,
+                               laplacian=laplacian)
+    else:
+        Z = Embedder(cfg, backend="distributed:reduce_scatter",
+                     plan_cache=None).fit(g, Y).transform()
+    return float(np.abs(Z - Zref).max())
+
+
+rng = np.random.default_rng(3)
+ends = rng.integers(0, 900, (2, 15000)).astype(np.int32)
+loops = rng.integers(0, 900, 501).astype(np.int32)
+uu, vv = np.concatenate([ends[0], loops]), np.concatenate([ends[1], loops])
+unit = Graph(uu, vv, np.ones(15501, np.float32), 1003)
+weighted = Graph(uu, vv, (rng.random(15501) + 0.5).astype(np.float32), 1003)
+Yc = make_labels(1003, K, 0.2, rng)
+out["rs_cases"] = {
+    "unit": rs_err(unit, Yc),
+    "weighted": rs_err(weighted, Yc),
+    "laplacian": rs_err(weighted, Yc, laplacian=True),
+    "functional": rs_err(weighted, Yc, functional=True),
+    "functional_laplacian": rs_err(weighted, Yc, laplacian=True,
+                                   functional=True)}
+
 # every mode through the Embedder: Z, and one embed's counters
 for mode in ("replicated", "reduce_scatter", "a2a", "ring"):
     name = "distributed:" + mode
@@ -111,6 +173,8 @@ for mode in ("replicated", "reduce_scatter", "a2a", "ring"):
         "dropped": e.last_info_["dropped"],
         "cap": bucket_cap(mode, 20008 // p, p,
                           e._plan.data["capacity_factor"]),
+        "bpt": (e._plan.data["rows"].shape[1] if "rows" in e._plan.data
+                else None),
         "delta": [a - b for a, b in zip(counts(name, mode), before)]}
 print("RESULT " + json.dumps(out))
 """
@@ -130,6 +194,8 @@ def res():
 
 
 S, S_PAD, N_PAD = 20007, 20008, 1004
+#: the kernel's tiles over N_PAD rows, and its edge block
+T, EB = 4, 512
 
 
 def test_runs_on_4_devices(res):
@@ -147,12 +213,35 @@ def test_fit_and_refits_match_oracle(res, step):
     assert res["auto"]["dropped"] == 0
 
 
-@pytest.mark.parametrize("arr", ["u", "v", "w"])
+@pytest.mark.parametrize("arr", ["rows", "src", "w"])
 def test_plan_edges_sharded_a_quarter_per_device(res, arr):
+    """The plan keeps each device's packed slots on that device, one
+    (T, BPT, 1, EB) set a device, and releases the placed edges."""
     placed = res["auto"]["placed"][arr]
     assert placed["spec"] == "PartitionSpec('edges',)"
     assert placed["devices"] == P_DEV
-    assert placed["shards"] == [S_PAD // P_DEV] * P_DEV
+    assert placed["shards"] == [T] * P_DEV
+    assert placed["shape"][0] == P_DEV * T
+    assert placed["shape"][2:] == [1, EB]
+    assert res["auto"]["edges_kept"] == []
+
+
+def test_device_packing_equals_host_packing(res):
+    """Each device's (rows, src, w) slots equal `kernels.ops.pack_edges`
+    of its own contributions, slot for slot, padded with zero blocks to
+    the fullest device's block count."""
+    assert res["auto"]["packed_as_host"] == [True] * (3 * P_DEV)
+    assert res["auto"]["placed"]["rows"]["shape"][1] == max(
+        res["auto"]["host_bpt"])
+
+
+@pytest.mark.parametrize("case", ["unit", "weighted", "laplacian",
+                                  "functional", "functional_laplacian"])
+def test_reduce_scatter_matches_oracle(res, case):
+    """Self-loops, isolated nodes, unlabeled donors, n a multiple of
+    neither the tile nor the mesh; through the Embedder and through
+    `gee_distributed`, with and without Laplacian scaling."""
+    assert res["rs_cases"][case] < 1e-4
 
 
 def test_second_refit_compiles_nothing(res):
@@ -161,12 +250,14 @@ def test_second_refit_compiles_nothing(res):
 
 @pytest.mark.parametrize("step", [0, 1, 2], ids=["fit", "refit", "refit2"])
 def test_counters_by_hand(res, step):
-    """Per embed: 2 s_pad slots; the 2 s real contributions split by
-    the donor's label; (p-1)/p of the (n_pad, K) f32 accumulator sent."""
+    """Per embed: p T BPT EB packed slots, padding included; the 2 s
+    real contributions split by the donor's label; (p-1)/p of the
+    (n_pad, K) f32 accumulator sent."""
     labeled = sum(res["auto"]["labeled"][:step + 1])
     k = step + 1
+    bpt = res["auto"]["placed"]["rows"]["shape"][1]
     assert res["auto"]["counts"][step] == [
-        k * 2 * S_PAD, labeled, k * 2 * S - labeled,
+        k * P_DEV * T * bpt * EB, labeled, k * 2 * S - labeled,
         k * (P_DEV - 1) * N_PAD * K * 4 // P_DEV]
 
 
@@ -179,7 +270,8 @@ def test_spans(res):
 def test_class_weights_give_make_w_z(res):
     """Each donor's weight read from the class weights by its label is
     the per-node weight `make_w` gathers per edge: the same Z, bit for
-    bit, through the Embedder and through `gee_distributed`."""
+    bit (replicated mode); and reduce_scatter gives the same Z through
+    the Embedder and through `gee_distributed`, bit for bit."""
     assert res["auto"]["same_as_make_w"]
     assert res["auto"]["same_as_gee_distributed"]
 
@@ -197,8 +289,11 @@ def test_mode_counts_by_hand(res, mode):
     m = res[mode]
     assert m["err"] < 1e-4 and m["dropped"] == 0
     cap, z = m["cap"], N_PAD * K * 4
-    slots = 2 * S_PAD if mode in ("replicated", "reduce_scatter") \
-        else P_DEV * P_DEV * cap
+    slots = {"replicated": 2 * S_PAD,
+             "reduce_scatter": P_DEV * T * res["auto"]["placed"]["rows"][
+                 "shape"][1] * EB}.get(mode, P_DEV * P_DEV * cap)
+    assert m["bpt"] == (None if mode != "reduce_scatter" else
+                        max(res["auto"]["host_bpt"]))
     sent = {"replicated": 2 * (P_DEV - 1) * z // P_DEV,
             "reduce_scatter": (P_DEV - 1) * z // P_DEV,
             "a2a": (P_DEV - 1) * cap * 12,

@@ -1,5 +1,7 @@
 """The main path's Pallas kernels compile for a TPU v5e at soc-pokec
-widths (n = 1.6M, 30M edges, K = 50), with ``interpret=False``.
+widths (n = 1.6M, 30M edges, K = 50), with ``interpret=False``; and the
+four-chip refit's embed runs the scatter kernel on each chip of a v5e
+2x2 at orkut-groups' size (n = 3.0M, 327M edges).
 
 The TPU compiler is installed with jaxlib and compiles for a described,
 unattached chip: this catches what interpret mode cannot — block
@@ -28,14 +30,18 @@ T = N // TILE_N
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -135,3 +141,33 @@ def test_kernel_name_survives_enclosing_jit(one_chip, kernel):
 
     _compiles_with_kernel(outer_step, kernel,
                           *[_spec(sh, dt, one_chip) for sh, dt in shapes])
+
+
+def test_reduce_scatter_embed_compiles_on_four_chips(topo):
+    """`distributed:reduce_scatter`'s embed (`core.distributed
+    ._packed_program`) on a 2x2 mesh: each chip's (T, 29, 1, EB) packed
+    slots (n_pad = 3.0M; the fullest tile of `er-4chip`'s graph fills
+    29 blocks) through the kernel, one cross-chip reduction that runs
+    as an all-reduce of its own, within a chip's 16 GB."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core import distributed as D
+    n = 3_000_000
+    mesh = Mesh(np.asarray(topo.devices), (D.AXIS,))
+    edges, rep = NamedSharding(mesh, P(D.AXIS)), NamedSharding(mesh, P())
+    slots = (4 * -(-n // TILE_N), 29, 1, EB)
+    compiled = _compiles_with_kernel(
+        D._packed_program(K, n, mesh, TILE_N, False),
+        "gee_scatter_pallas",
+        _spec(slots, jnp.int32, edges), _spec(slots, jnp.int32, edges),
+        _spec(slots, jnp.float32, edges), _spec((n,), jnp.int32, rep),
+        _spec((K,), jnp.float32, rep))
+    # an instruction of the entry computation named all-reduce, the name
+    # the device trace shows for it (not folded into a fusion)
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert re.search(r"%all-reduce(\.\d+)? = [^\n]*all-reduce\(", entry)
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes) < 16e9
