@@ -35,24 +35,21 @@ from repro.graph.edges import Graph, make_labels
 from repro.graph.sources import SyntheticSource
 
 # (backend, n, s, cfg overrides) — pallas interpret mode and the p=1
-# distributed modes are correctness paths on this container, so they
+# distributed backend are correctness paths on this container, so they
 # run scaled-down; xla/numpy/streaming run at the real CPU hot-path size
 SIZES = {
     "xla": (100_000, 1_000_000, {}),
     "numpy": (100_000, 1_000_000, {}),
     "streaming": (100_000, 1_000_000, {"chunk_size": 1 << 18}),
     "pallas": (2_000, 16_000, {"tile_n": 256, "edge_block": 256}),
-    "distributed:replicated": (20_000, 200_000, {}),
     "distributed:reduce_scatter": (20_000, 200_000, {}),
-    "distributed:a2a": (20_000, 200_000, {}),
-    "distributed:ring": (20_000, 200_000, {}),
 }
 QUICK_SIZES = {
     "xla": (500, 4_000, {}),
     "numpy": (500, 4_000, {}),
     "streaming": (500, 4_000, {"chunk_size": 1 << 10}),
     "pallas": (500, 4_000, {"tile_n": 64, "edge_block": 128}),
-    "distributed:ring": (500, 4_000, {}),
+    "distributed:reduce_scatter": (500, 4_000, {}),
 }
 K = 16
 

@@ -43,7 +43,7 @@ def run() -> None:
                  f"coll={rec.get('collective_s', 0):.3e};{mfu}")
     # C6: GEE memory-bound check
     for mesh_name in sorted(os.listdir(ART)):
-        p = os.path.join(ART, mesh_name, "gee__ring.json")
+        p = os.path.join(ART, mesh_name, "gee__reduce_scatter.json")
         if os.path.exists(p):
             rec = json.load(open(p))
             ratio = (max(rec["memory_s"], rec["collective_s"])

@@ -344,8 +344,6 @@ def four_chip_phase(src, g, Y, K: int):
         jax.block_until_ready(emb.Z_)
     check("auto backend", emb.backend.name == "distributed:reduce_scatter",
           f"resolved={emb.backend.name} devices={len(jax.devices())}")
-    check("no dropped contributions", emb.last_info_["dropped"] == 0,
-          f"dropped={emb.last_info_['dropped']}")
     with phase("four.oracle (numpy, host)"):
         Z_ref = oracle(g, Y, K)
     z_close("fit Z[distributed:reduce_scatter] vs gee_numpy", emb.Z_,

@@ -1,8 +1,8 @@
 """Distributed GEE demo: embed a multi-million-edge graph through the
-unified Embedder API's `distributed:*` backends on 8 (placeholder)
-devices — the exact code path the 512-chip dry-run lowers, at laptop
-scale.  The plan (padding + exact capacity measurement) is built once
-per backend; the timed fit reuses it.
+unified Embedder API on 8 (placeholder) devices, where `auto` resolves
+to `distributed:reduce_scatter` — the code path the 512-chip dry-run
+lowers, at laptop scale.  The plan (padding + packing each device's
+edges on the devices) is built once; the timed refit reuses it.
 
     python examples/distributed_gee.py
 """
@@ -23,22 +23,20 @@ g, truth = sbm(n, K, s, p_in=0.85, seed=0)
 g = shuffle_edges(g, seed=1)
 Y = make_labels(n, K, 0.10, np.random.default_rng(0), true_labels=truth)
 P = len(jax.devices())
-print(f"devices={P} edges={s:,} (capacity factor measured in plan)")
+print(f"devices={P} edges={s:,}")
 
-for mode in ("ring", "a2a", "reduce_scatter"):
-    emb = Embedder(EncoderConfig(K=K), backend=f"distributed:{mode}")
-    emb.fit(g, Y)                                  # plan + warm compile
-    t0 = time.perf_counter()
-    emb.refit(Y)                                   # cached plan
-    jax.block_until_ready(emb.Z_)
-    dt = time.perf_counter() - t0
-    pred = emb.predict()
-    mask = Y < 0
-    acc = (pred[mask] == truth[mask]).mean()
-    print(f"mode={mode:14s} {dt*1e3:9.1f} ms  "
-          f"({s/dt/1e6:6.1f} M edges/s)  "
-          f"dropped={emb.last_info_['dropped']}  "
-          f"plan={emb.plan_stats}  unlabeled-acc={acc:.3f}")
+emb = Embedder(EncoderConfig(K=K))                 # backend="auto"
+emb.fit(g, Y)                                      # plan + warm compile
+t0 = time.perf_counter()
+emb.refit(Y)                                       # cached plan
+jax.block_until_ready(emb.Z_)
+dt = time.perf_counter() - t0
+pred = emb.predict()
+mask = Y < 0
+acc = (pred[mask] == truth[mask]).mean()
+print(f"backend={emb.backend.name} {dt*1e3:9.1f} ms  "
+      f"({s/dt/1e6:6.1f} M edges/s)  "
+      f"plan={emb.plan_stats}  unlabeled-acc={acc:.3f}")
 """
 
 

@@ -8,8 +8,7 @@ remain the backend *internals* and are re-exported here (lazily, PEP
 
     gee_refine, gee_streaming, gee_apply_delta, gee_dense_oracle,
     make_w                      <- repro.core.gee
-    gee_distributed, gee_sharded, edge_mesh, exact_capacity_factor
-                                <- repro.core.distributed
+    gee_distributed, edge_mesh  <- repro.core.distributed
     gee_numpy, gee_python       <- repro.core.ref_python
 
 (`repro.core.gee` stays the submodule — the function is
@@ -27,9 +26,7 @@ _FORWARDS = {
     "gee_dense_oracle": "repro.core.gee",
     "make_w": "repro.core.gee",
     "gee_distributed": "repro.core.distributed",
-    "gee_sharded": "repro.core.distributed",
     "edge_mesh": "repro.core.distributed",
-    "exact_capacity_factor": "repro.core.distributed",
     "gee_numpy": "repro.core.ref_python",
     "gee_python": "repro.core.ref_python",
 }

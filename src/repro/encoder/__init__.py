@@ -8,8 +8,8 @@
     emb.refit(new_Y)              # cached plan, no host re-packing
 
 Backends (select by name, register new ones with `register_backend`):
-numpy, xla, pallas, streaming, distributed:{replicated, reduce_scatter,
-a2a, ring} — or "auto" (the default), resolved at plan time from
+numpy, xla, pallas, streaming, distributed:reduce_scatter — or "auto"
+(the default), resolved at plan time from
 (n, s, device kind, device count) via the overridable
 `backends.AUTO_POLICY` table.  All produce the same Z (see
 tests/test_encoder.py's cross-backend conformance suite); they differ

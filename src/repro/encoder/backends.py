@@ -12,13 +12,12 @@ in where the scatter runs and how contributions move:
                   changes re-resolve on device and never re-pack.
   streaming       chunked accumulate (O(chunk) device memory) —
                   the out-of-core / serving-rebuild path.
-  distributed:M   `core.distributed` for M in
-                  {replicated, reduce_scatter, a2a, ring} — SPMD
-                  collectives; the plan pads edges/rows to the mesh,
-                  places the edges sharded over it and measures the
-                  exact zero-drop capacity factor once; reduce_scatter
-                  packs each chip's edges on the chips for the pallas
-                  kernel once per plan.
+  distributed:reduce_scatter
+                  `core.distributed` — SPMD over the device mesh; the
+                  plan pads edges/rows to the mesh, places the edges
+                  sharded over it and packs each chip's edges on the
+                  chips for the pallas kernel once; each embed runs the
+                  kernel on every chip and one reduce-scatter.
 
 Register new strategies with ``@register_backend("name")``; callers
 select them by name through ``Embedder(..., backend="name")`` without
@@ -88,9 +87,6 @@ class Backend:
     """One execution strategy: label-free `plan`, label-dependent `embed`."""
 
     name: str = "?"
-    #: scatter-path backends reproduce the oracle to float tolerance;
-    #: bucketed collective modes additionally depend on capacity padding.
-    exact: bool = True
     #: bump when the plan_host artifact layout changes — stale disk
     #: entries from older code then read as misses, not wrong plans
     plan_version: int = 1
@@ -101,13 +97,8 @@ class Backend:
     #: contributions pre-bucketed by owned destination
     supports_row_partition: bool = False
 
-    def cache_context(self, *, mesh=None) -> str:
-        """Runtime context baked into the persistent-cache key (e.g.
-        device count, which distributed capacity factors depend on)."""
-        return ""
-
     def plan_host(self, graph: Graph, config: EncoderConfig,
-                  w_eff: np.ndarray, *, mesh=None) -> Dict:
+                  w_eff: np.ndarray) -> Dict:
         """Backend-specific expensive host artifacts (numpy arrays /
         scalars only; "w_eff" is added by `plan`)."""
         return {}
@@ -134,7 +125,7 @@ class Backend:
             w_eff = effective_weights(graph, config)
             keep_w = config.laplacian and config.row_partition is None
             host = {**({"w_eff": w_eff} if keep_w else {}),
-                    **self.plan_host(graph, config, w_eff, mesh=mesh)}
+                    **self.plan_host(graph, config, w_eff)}
         if config.row_partition is not None:
             # owned plans folded the scaling into o_w: don't retain (or,
             # on a cache hit, rebuild) a second full-length copy that no
@@ -157,8 +148,7 @@ class Backend:
         (`core.gee.class_weights`).  A contribution's weight depends
         only on its class, which is its column of Z: pallas and
         distributed:reduce_scatter scale Z's columns by class_w; the
-        other distributed backends weight each contribution by its
-        donor's class weight; the rest by the per-node
+        rest weight each contribution by the per-node
         `make_w(Yj, K, class_w)`."""
         raise NotImplementedError
 
@@ -186,7 +176,7 @@ class NumpyBackend(Backend):
 
     supports_row_partition = True
 
-    def plan_host(self, graph, config, w_eff, *, mesh=None):
+    def plan_host(self, graph, config, w_eff):
         if config.row_partition is None:
             return {}
         return _owned_plan_host(graph, config, w_eff)
@@ -225,7 +215,7 @@ class XlaBackend(Backend):
 
     supports_row_partition = True
 
-    def plan_host(self, graph, config, w_eff, *, mesh=None):
+    def plan_host(self, graph, config, w_eff):
         if config.row_partition is None:
             return {}
         return _owned_plan_host(graph, config, w_eff)
@@ -302,7 +292,7 @@ class PallasBackend(Backend):
     #: v3: packed blocks are (T, BPT, 1, EB), the layout Mosaic tiles
     plan_version = 3
 
-    def plan_host(self, graph, config, w_eff, *, mesh=None):
+    def plan_host(self, graph, config, w_eff):
         from repro.kernels.ops import _round_up, pack_edges
         if config.row_partition is not None:
             lo, hi = config.row_partition
@@ -366,7 +356,7 @@ class StreamingBackend(Backend):
 
     supports_row_partition = True
 
-    def plan_host(self, graph, config, w_eff, *, mesh=None):
+    def plan_host(self, graph, config, w_eff):
         if config.row_partition is None:
             return {}
         # the O(s) destination bucketing is the expensive half here —
@@ -412,103 +402,66 @@ class StreamingBackend(Backend):
         return rows if plan.config.row_partition is not None else 2 * rows
 
 
+@register_backend("distributed:reduce_scatter")
 class DistributedBackend(Backend):
-    """SPMD collectives over the edge mesh (`core.distributed`).
+    """SPMD over the edge mesh (`core.distributed`).
 
-    The plan pads edges and rows to the mesh, places the padded arrays
-    sharded over the mesh's edge axis (span ``encoder.place``), and —
-    for bucketed modes — measures the exact zero-drop capacity factor
-    from the owner histogram (an O(s) host pass now done once instead
-    of per fit).  The capacity factor depends on the device count, so
-    it is the persisted host artifact and the device count is baked
-    into the cache key (`cache_context`); padding and placement are
-    per-process finalize work.
-
-    reduce_scatter then packs, inside the same span, each chip's
+    The plan pads edges (at least one a chip) and rows to the mesh,
+    places the padded arrays sharded over the mesh's edge axis and
+    packs, inside the same span (``encoder.place``), each chip's
     contributions on the chips into the pallas kernel's (T, BPT, 1, EB)
     destination-tiled slots (`pack_sharded`: one sort, one read-back of
-    the fullest tile, one scatter into the slots) and keeps only the
-    packed slots: like the pallas backend's, they are label-free, so
-    no refit packs again.  Its embed gathers each slot's label, runs
-    the kernel on every chip into a full (n_pad, K) partial Z, reduces
-    it with one psum_scatter and scales each row shard's columns by the
-    class weights (`gee_packed`).  The other modes weight each
-    contribution by its donor's class weight and scatter with XLA
-    (`gee_sharded`).
+    the fullest tile, one scatter into the slots).  It keeps only the
+    packed slots: like the pallas backend's, they are label-free, so no
+    refit packs again, and padding and packing are per-process finalize
+    work.
 
     Each embed replicates the labels and the Embedder's class weights
-    over the mesh and dispatches the mode's one jitted program (span
-    ``encoder.shard_embed``).  It counts the least bytes one chip must
-    send in the mode's collective
-    (``repro_distributed_collective_bytes_total{mode}``,
-    `core.distributed.collective_bytes`; the lowered collective may
-    send more).
+    over the mesh and dispatches one jitted program (span
+    ``encoder.shard_embed``, `gee_packed`): it gathers each slot's
+    label, runs the kernel on every chip into a full (n_pad, K) partial
+    Z, reduces it with one psum_scatter and scales each row shard's
+    columns by the class weights.  It counts the least bytes one chip
+    must send in that collective
+    (``repro_distributed_collective_bytes_total{mode="reduce_scatter"}``,
+    `core.distributed.collective_bytes`; the lowered collective may send
+    more).
     """
-
-    mode = "ring"
-    exact = False          # bucketed modes depend on capacity padding
-
-    @staticmethod
-    def _mesh(mesh):
-        from repro.core.distributed import edge_mesh
-        return mesh if mesh is not None else edge_mesh()
-
-    def cache_context(self, *, mesh=None) -> str:
-        return f"nd={self._mesh(mesh).devices.size}"
-
-    def plan_host(self, graph, config, w_eff, *, mesh=None):
-        from repro.core.distributed import exact_capacity_factor
-        nd = self._mesh(mesh).devices.size
-        cf = config.capacity_factor
-        if cf is None and self.mode in ("a2a", "ring"):
-            cf = exact_capacity_factor(graph, nd)
-        return {"capacity_factor": cf if cf is not None else 2.0}
 
     def plan_finalize(self, p, graph, *, mesh=None):
         from jax.sharding import NamedSharding, PartitionSpec
-        from repro.core.distributed import (AXIS, bucket_cap,
-                                            collective_bytes, embed_slots,
-                                            pack_sharded, pad_rows)
+        from repro.core.distributed import (AXIS, collective_bytes,
+                                            edge_mesh, pack_sharded,
+                                            pad_rows)
         from repro.kernels.gee_scatter import resolve_interpret
         cfg = p.config
-        mesh = self._mesh(mesh)
+        mesh = mesh if mesh is not None else edge_mesh()
         nd = mesh.devices.size
         n_pad = pad_rows(graph.n, nd)
-        s_pad = pad_rows(graph.s, nd)
+        s_pad = pad_rows(max(graph.s, 1), nd)
         g = Graph(np.asarray(graph.u), np.asarray(graph.v), p.w_eff,
                   graph.n).pad_to(s_pad)
-        cf = float(p.host["capacity_factor"])
-        cap = bucket_cap(self.mode, s_pad // nd, nd, cf)
-        packed = self.mode == "reduce_scatter"
         # straight from host memory: each chip receives only its s/p
         # edges, and no embed moves them again
         with obs.span("encoder.place", backend=self.name,
                       s=s_pad) as sp:
             edges = jax.device_put(
                 (g.u, g.v, g.w), NamedSharding(mesh, PartitionSpec(AXIS)))
-            if packed:
-                edges = pack_sharded(*edges, n=n_pad, mesh=mesh,
-                                     tile_n=cfg.tile_n,
-                                     edge_block=cfg.edge_block)
+            edges = pack_sharded(*edges, n=n_pad, mesh=mesh,
+                                 tile_n=cfg.tile_n,
+                                 edge_block=cfg.edge_block)
             edges = sp.fence(edges)
-        p.data = {"mesh": mesh, "n_pad": n_pad, "capacity_factor": cf,
-                  "collective_bytes": collective_bytes(
-                      self.mode, n_pad, cfg.K, nd, cap)}
-        if packed:
-            p.data.update(zip(("rows", "src", "w"), edges),
-                          slots=int(edges[0].size),
-                          interpret=resolve_interpret(cfg.interpret))
-        else:
-            p.data.update(zip(("u", "v", "w"), edges),
-                          slots=embed_slots(self.mode, s_pad, nd, cap))
+        p.data = {"mesh": mesh, "n_pad": n_pad,
+                  "collective_bytes": collective_bytes(n_pad, cfg.K, nd),
+                  **dict(zip(("rows", "src", "w"), edges)),
+                  "interpret": resolve_interpret(cfg.interpret)}
 
     def slots(self, plan):
-        return plan.data["slots"]
+        return int(plan.data["rows"].size)         # p * T * BPT * EB
 
     def embed(self, plan, Yj, class_w):
         from jax.sharding import NamedSharding, PartitionSpec
-        from repro.core.distributed import (SCATTER_MODES, gee_packed,
-                                            gee_sharded)
+        from repro.core.distributed import gee_packed
         d, cfg = plan.data, plan.config
         pad = d["n_pad"] - plan.n
         if pad:
@@ -516,34 +469,12 @@ class DistributedBackend(Backend):
         Yj, class_w = jax.device_put(
             (Yj, class_w), NamedSharding(d["mesh"], PartitionSpec()))
         with obs.span("encoder.shard_embed", backend=self.name):
-            if self.mode == "reduce_scatter":
-                Z = gee_packed(d["rows"], d["src"], d["w"], Yj, class_w,
-                               K=cfg.K, n=d["n_pad"], mesh=d["mesh"],
-                               tile_n=cfg.tile_n,
-                               interpret=d["interpret"])
-                dropped = 0
-            else:
-                Z, dropped = gee_sharded(
-                    d["u"], d["v"], d["w"], Yj, class_w, K=cfg.K,
-                    n=d["n_pad"], mesh=d["mesh"], mode=self.mode,
-                    capacity_factor=d["capacity_factor"])
+            Z = gee_packed(d["rows"], d["src"], d["w"], Yj, class_w,
+                           K=cfg.K, n=d["n_pad"], mesh=d["mesh"],
+                           tile_n=cfg.tile_n, interpret=d["interpret"])
         obs.counter("repro_distributed_collective_bytes_total",
-                    d["collective_bytes"], mode=self.mode)
-        # the scatter modes drop nothing: no read back, so the host runs
-        # on while the device works
-        info = {"dropped": (0 if self.mode in SCATTER_MODES
-                            else int(dropped))}
-        return (Z[:plan.n] if pad else Z), info
-
-
-for _mode in ("replicated", "reduce_scatter", "a2a", "ring"):
-    # replicated / reduce_scatter are pure scatter+collective paths
-    # (float-exact); a2a / ring bucket with capacity padding.
-    register_backend(f"distributed:{_mode}")(
-        type(f"Distributed{_mode.title().replace('_', '')}Backend",
-             (DistributedBackend,),
-             {"mode": _mode,
-              "exact": _mode in ("replicated", "reduce_scatter")}))
+                    d["collective_bytes"], mode="reduce_scatter")
+        return (Z[:plan.n] if pad else Z), {}
 
 
 # -- backend="auto": the plan-time selection policy -------------------------

@@ -4,7 +4,7 @@ The paper's algorithm has one mathematical definition (Z = scatter-add
 of per-edge label contributions) and many execution strategies.  The
 config captures the math-level choices — number of classes, Laplacian
 scaling, refinement schedule, output dtype — plus the per-backend
-tuning knobs (tile sizes, chunk sizes, capacity factors) that change
+tuning knobs (tile sizes, chunk sizes) that change
 performance but never the answer.  Frozen and hashable so plans can be
 keyed on it.
 """
@@ -44,7 +44,7 @@ class EncoderConfig:
                   (tier 1 and tier 2), so a resharded deployment can
                   never hit a stale plan.  Supported by the numpy /
                   xla / streaming / pallas backends (the distributed
-                  collective modes shard internally instead).
+                  backend shards internally instead).
 
     Backend tuning (never change Z, only speed/memory):
       backend     execution strategy by registry name, or "auto"
@@ -54,10 +54,6 @@ class EncoderConfig:
                   `Embedder(..., backend=...)` argument overrides this.
       tile_n, edge_block, interpret   Pallas kernel geometry.
       chunk_size                      streaming chunk length.
-      capacity_factor                 distributed bucket padding; None
-                                      measures the exact zero-drop factor
-                                      from the owner histogram (cached in
-                                      the plan).
     """
 
     K: int
@@ -76,8 +72,6 @@ class EncoderConfig:
     interpret: Union[bool, str] = "auto"
     # streaming
     chunk_size: int = 1 << 20
-    # distributed
-    capacity_factor: Optional[float] = None
 
     def __post_init__(self) -> None:
         if self.K < 1:

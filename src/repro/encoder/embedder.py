@@ -133,7 +133,7 @@ class Embedder:
         Tier 2 is content-addressed and survives the process: on a tier
         1 miss, the graph's fingerprint + resolved backend + config key
         a persistent entry holding the plan's host half — a hit skips
-        `plan_host` (packing, capacity measurement, Laplacian degrees)
+        `plan_host` (packing, Laplacian degrees)
         and only re-runs device placement.  Stale or corrupt entries
         fall back to a full rebuild; `plan_cache=None` disables the
         tier (or set REPRO_PLAN_CACHE=off process-wide)."""
@@ -146,8 +146,8 @@ class Embedder:
                 raise ValueError(
                     f"backend {backend.name!r} has no owned-rows "
                     "accumulate path (row_partition) — only the "
-                    "distributed:* collective modes lack one (they "
-                    "shard internally across the device mesh instead); "
+                    "distributed backend lacks one (it shards "
+                    "internally across the device mesh instead); "
                     "use one of the partition-aware backends: "
                     f"{', '.join(partition_backends())}")
             if rp[1] > graph.n:
@@ -172,7 +172,7 @@ class Embedder:
             cache = self.plan_cache if backend.persistable else None
             if cache is not None:
                 meta = cache.describe(graph.fingerprint(), backend,
-                                      self.config, mesh=self.mesh)
+                                      self.config)
                 host = cache.load(meta)
             if host is not None:
                 self._bump_plan_stat("disk_hits")

@@ -4,9 +4,8 @@ Every GEE backend splits into two phases:
 
   1. **plan** — host-side preprocessing that depends only on the edge
      multiset and the config: Laplacian degree precompute + weight
-     scaling, padding, destination-tile packing (Pallas), owner-bucket
-     capacity measurement and edge padding (distributed), chunking
-     (streaming), device placement.  O(s) to O(s log s).
+     scaling, padding, destination-tile packing (Pallas, and on the
+     chips for distributed), chunking (streaming), device placement.  O(s) to O(s log s).
   2. **embed** — the label-dependent pass: resolve per-edge classes and
      projection weights from the *current* Y and scatter.  O(s) device
      work, no host packing.
@@ -21,7 +20,7 @@ new plan).
 The plan itself splits once more, into a **host** half and a **device**
 half (`Backend.plan_host` / `Backend.plan_finalize`): the host half is
 the expensive, device-free preprocessing (w_eff, Pallas destination
-packing, distributed capacity factors) and is what the persistent
+packing) and is what the persistent
 cross-process plan cache (`repro.encoder.plan_cache`) persists, keyed
 on the graph's content fingerprint; the device half (uploads, mesh
 placement, chunk views) is rebuilt cheaply in every process.

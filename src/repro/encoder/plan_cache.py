@@ -5,11 +5,10 @@ dies with the process.  GEE's practical workload re-embeds the *same
 graph* many times as labels churn, across restarts, CI reruns, and
 serving replicas; for those, graph identity is content, not arrays.
 This module stores each plan's **host half** (w_eff, Pallas destination
-packing, distributed capacity factors — everything expensive and
-device-free) on disk, keyed on:
+packing — everything expensive and device-free) on disk, keyed on:
 
     (graph fingerprint, backend name, backend plan_version,
-     config fields, backend cache context e.g. device count)
+     config fields)
 
 so a fresh process skips host packing entirely and goes straight to
 `Backend.plan_finalize` (cheap device placement).
@@ -90,15 +89,14 @@ class PlanDiskCache:
 
     # -- keying -----------------------------------------------------------
 
-    def describe(self, fingerprint: str, backend, config, *,
-                 mesh=None) -> Dict[str, Any]:
+    def describe(self, fingerprint: str, backend, config
+                 ) -> Dict[str, Any]:
         """The full metadata a cached entry must match to be served."""
         return {"format": FORMAT_VERSION,
                 "fingerprint": fingerprint,
                 "backend": backend.name,
                 "plan_version": backend.plan_version,
-                "config": config_token(config),
-                "context": backend.cache_context(mesh=mesh)}
+                "config": config_token(config)}
 
     @staticmethod
     def key(meta: Dict[str, Any]) -> str:

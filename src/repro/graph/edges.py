@@ -63,7 +63,7 @@ def edge_fingerprint(n: int, u, v, w) -> str:
     """Content fingerprint of an edge multiset: hash over (n, u, v, w).
 
     O(s) over raw bytes — cheap relative to any plan build (sorting,
-    capacity histograms), and the cross-process cache key for the
+    Laplacian degrees), and the cross-process cache key for the
     encoder's persistent plan tier.  ORDER-SENSITIVE by design: plan
     artifacts (packing layouts, chunk boundaries) depend on edge order,
     so a permuted multiset correctly reads as different content."""

@@ -4,11 +4,11 @@ Two axes, matching the two halves of the system:
 
 * **Edge partitioning** (training/offline): the paper gets load
   balance from Ligra's dynamic scheduling; with static SPMD shards we
-  get it from randomization: a shuffled edge list makes every shard's
-  per-owner bucket sizes concentrate around the mean (Chernoff), which
-  is what the capacity-padded a2a/ring modes rely on.  `plan_capacity`
-  quantifies the tail so callers can pick a factor with a target
-  overflow probability instead of guessing.
+  get it from randomization: `shuffle_edges` spreads a skewed edge
+  list so every shard sees each destination row's contributions in
+  about its mean share, and no chip's destination tiles fill far past
+  the others' (the distributed backend pads every chip to the fullest
+  tile over all chips).
 
 * **Row partitioning** (serving): `RowPartition` splits the n embedding
   rows into contiguous slices, one per `serving.EmbeddingShard`.  GEE's
@@ -31,30 +31,6 @@ from repro.graph.edges import Graph
 
 def shuffle_edges(g: Graph, seed: int = 0) -> Graph:
     return g.permuted(np.random.default_rng(seed))
-
-
-def owner_histogram(g: Graph, p: int) -> np.ndarray:
-    """(p, p) matrix: [shard, owner] contribution counts (diagnostics)."""
-    s_pad = ((g.s + p - 1) // p) * p
-    gp = g.pad_to(s_pad)
-    rows = ((g.n + p - 1) // p)
-    hist = np.zeros((p, p), np.int64)
-    per = s_pad // p
-    for shard in range(p):
-        sl = slice(shard * per, (shard + 1) * per)
-        dst = np.concatenate([gp.u[sl], gp.v[sl]])
-        np.add.at(hist[shard], np.minimum(dst // rows, p - 1), 1)
-    return hist
-
-
-def plan_capacity(s: int, n: int, p: int, overflow_target: float = 1e-6
-                  ) -> float:
-    """Capacity factor such that P(bucket > cap) < target under a
-    balanced multinomial (Chernoff bound: cap = mu + 3*sigma-ish)."""
-    mu = 2 * (s / p) / p
-    sigma = np.sqrt(max(mu, 1.0))
-    z = np.sqrt(2 * np.log(p * p / max(overflow_target, 1e-12)))
-    return float((mu + z * sigma) / max(mu, 1.0))
 
 
 class RowPartition:

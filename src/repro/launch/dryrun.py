@@ -241,10 +241,13 @@ def run_cell(arch, shape_name, *, multi_pod=False, impl="flash",
 # ---------------------------------------------------------------------------
 
 
-def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
-            s=1_800_000_000, K=50, save=True):
-    from repro.core.distributed import (AXIS, gee_a2a_steady, gee_packed,
-                                        gee_sharded)
+def run_gee(*, multi_pod=False, n=65_000_000, s=1_800_000_000, K=50,
+            save=True):
+    """Lower and compile `distributed:reduce_scatter`'s per-step
+    program (`gee_packed`) over each chip's packed (T, BPT, 1, EB)
+    slots.  Packing runs once per plan and its fullest tile fixes BPT,
+    which needs the graph: the dry run takes the mean tile's."""
+    from repro.core.distributed import AXIS, gee_packed
     from repro.core.gee import class_weights
     from repro.kernels.gee_scatter import EDGE_BLOCK, TILE_N
     mesh = make_gee_mesh(multi_pod=multi_pod)
@@ -253,75 +256,34 @@ def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
     s_pad = ((s + p - 1) // p) * p
     espec = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(AXIS))
     rspec = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-    u = jax.ShapeDtypeStruct((s_pad,), jnp.int32, sharding=espec)
-    w = jax.ShapeDtypeStruct((s_pad,), jnp.float32, sharding=espec)
     Y = jax.ShapeDtypeStruct((n_pad,), jnp.int32, sharding=rspec)
+    T = -(-n_pad // TILE_N)
+    bpt = -(-2 * (s_pad // p) // (T * EDGE_BLOCK))
+    shape = (p * T, bpt, 1, EDGE_BLOCK)
+    si = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=espec)
+    sf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=espec)
+
+    def fn(rows, src, w, Y):
+        return gee_packed(rows, src, w, Y, class_weights(Y, K), K=K,
+                          n=n_pad, mesh=mesh)
 
     t0 = time.time()
-    if mode == "a2a_steady":
-        # pre-bucketed steady-state (refinement-loop) step: buckets are
-        # built once at ingestion; per-iteration program is just
-        # gather -> all_to_all -> scatter (no sort).
-        cap = int(np.ceil(2 * (s_pad // p) / p * 2.0)) + 8
-        bi = jax.ShapeDtypeStruct((p * p, cap), jnp.int32, sharding=espec)
-        bf = jax.ShapeDtypeStruct((p * p, cap), jnp.float32,
-                                  sharding=espec)
-
-        def fn(b_dst, b_src, b_w, Y):
-            return gee_a2a_steady(b_dst, b_src, b_w, Y, K=K, n_pad=n_pad,
-                                  mesh=mesh)
-
-        lowered = jax.jit(fn).lower(bi, bi, bf, Y)
-    elif mode == "reduce_scatter":
-        # the per-step program over each chip's packed (T, BPT, 1, EB)
-        # slots; packing runs once per plan and its fullest tile fixes
-        # BPT, which needs the graph: the dry run takes the mean tile's
-        T = -(-n_pad // TILE_N)
-        bpt = -(-2 * (s_pad // p) // (T * EDGE_BLOCK))
-        shape = (p * T, bpt, 1, EDGE_BLOCK)
-        si = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=espec)
-        sf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=espec)
-
-        def fn(rows, src, w, Y):
-            return (gee_packed(rows, src, w, Y, class_weights(Y, K), K=K,
-                               n=n_pad, mesh=mesh),
-                    jnp.zeros((), jnp.int32))
-
-        lowered = jax.jit(fn).lower(si, si, sf, Y)
-    else:
-        def fn(u, v, w, Y):
-            Z, dropped = gee_sharded(u, v, w, Y, class_weights(Y, K), K=K,
-                                     n=n_pad, mesh=mesh, mode=mode)
-            return Z, dropped
-
-        lowered = jax.jit(fn).lower(u, u, w, Y)
+    lowered = jax.jit(fn).lower(si, si, sf, Y)
     compiled = lowered.compile()
     dt = time.time() - t0
-
-    class _Shape:
-        name = f"gee_{mode}"
-        kind = "gee"
-        tokens = s
-        global_batch = 1
 
     ca = compiled.cost_analysis()
     if isinstance(ca, list):
         ca = ca[0]
     colls = RL.parse_collectives(compiled.as_text())
     bytes_dev = float(ca.get("bytes accessed", 0.0))
-    if mode == "ring":
-        # the ppermute + accumulate live inside a fori_loop that XLA's
-        # cost analysis counts once; the ring runs p-1 iterations.
-        colls["collective-permute"]["wire_bytes"] *= (p - 1)
-        rows = n_pad // p
-        cap = int(np.ceil(2 * (s_pad // p) / p * 2.0)) + 8
-        bytes_dev += (p - 2) * (2 * rows * K * 4 + cap * 12)
     wire = sum(c["wire_bytes"] for c in colls.values())
     ma = compiled.memory_analysis()
     chip = RL.peaks(RL.DRYRUN_KIND)
     mesh_name = ("pod2x16x16" if multi_pod else "pod16x16")
     rec = {
-        "arch": "gee-friendster", "shape": f"gee_{mode}", "mesh": mesh_name,
+        "arch": "gee-friendster", "shape": "gee_reduce_scatter",
+        "mesh": mesh_name,
         "chips": p, "compile_s": dt,
         "flops_per_device": float(ca.get("flops", 0.0)),
         "bytes_per_device": bytes_dev,
@@ -338,9 +300,9 @@ def run_gee(*, multi_pod=False, mode="ring", n=65_000_000,
     if save:
         d = os.path.join(ART, mesh_name)
         os.makedirs(d, exist_ok=True)
-        with open(os.path.join(d, f"gee__{mode}.json"), "w") as f:
+        with open(os.path.join(d, "gee__reduce_scatter.json"), "w") as f:
             json.dump(rec, f, indent=1)
-    print(f"[dryrun] {mesh_name} gee-friendster mode={mode:14s} "
+    print(f"[dryrun] {mesh_name} gee-friendster reduce_scatter "
           f"compile={dt:6.1f}s flops/dev={rec['flops_per_device']:.3e} "
           f"bytes/dev={rec['bytes_per_device']:.3e} "
           f"coll/dev={wire:.3e} dom={rec['dominant']} "
@@ -355,8 +317,6 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--gee", action="store_true")
-    ap.add_argument("--gee-mode", default=None,
-                    help="ring|a2a|reduce_scatter|replicated (default all)")
     ap.add_argument("--impl", default="flash",
                     choices=["flash", "triangular", "full"])
     ap.add_argument("--no-fsdp", action="store_true")
@@ -368,14 +328,11 @@ def main():
 
     failures = []
     if args.gee:
-        modes = [args.gee_mode] if args.gee_mode else \
-            ["ring", "a2a", "reduce_scatter", "replicated"]
-        for mode in modes:
-            try:
-                run_gee(multi_pod=args.multi_pod, mode=mode)
-            except Exception as e:
-                traceback.print_exc()
-                failures.append(("gee", mode, repr(e)))
+        try:
+            run_gee(multi_pod=args.multi_pod)
+        except Exception as e:
+            traceback.print_exc()
+            failures.append(("gee", "reduce_scatter", repr(e)))
     elif args.all:
         for arch in list_archs():
             cfg = get_config(arch)

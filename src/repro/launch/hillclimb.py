@@ -7,7 +7,7 @@ three chosen cells and print before/after roofline terms.
 The three pairs (selection rationale in EXPERIMENTS.md §Perf):
   1. qwen1.5-110b x train_4k   — worst memory blow-up (biggest dense)
   2. grok-1-314b  x train_4k   — most collective-bound
-  3. gee-friendster (ring)     — the paper's own workload
+  3. gee-friendster (reduce_scatter) — the paper's own workload
 
 Each variant re-lowers the cell with one change and records the probe
 terms under a tag; compare with
@@ -66,10 +66,7 @@ VARIANTS = {
                             kw=dict(impl="triangular", accum_steps=8,
                                     tag="tri-accum8")),
     # --- GEE friendster: the paper's workload ---------------------------
-    "gee-ring": dict(kind="gee", mode="ring"),
-    "gee-a2a": dict(kind="gee", mode="a2a"),
-    "gee-rs": dict(kind="gee", mode="reduce_scatter"),
-    "gee-repl": dict(kind="gee", mode="replicated"),
+    "gee-rs": dict(kind="gee"),
     # --- kernel-geometry autotune (repro.launch.autotune): coordinate
     # descent over TILE_N/EDGE_BLOCK (scatter) and block_rows (fused
     # top-k), reporting achieved-vs-roofline HBM bandwidth.  Run with
@@ -117,7 +114,7 @@ def main():
     for name in args.variant:
         v = VARIANTS[name]
         if v["kind"] == "gee":
-            run_gee(mode=v["mode"])
+            run_gee()
         elif v["kind"] == "kernel":
             _run_kernel_tune(v["fn"], args.quick)
         else:

@@ -17,11 +17,13 @@ P_DEV = 4
 K = 7
 
 SCRIPT = r"""
-import json
+import json, sys
 import numpy as np, jax
+sys.path.insert(0, %(tests)r)
+from test_encoder import CFG, _cases
 from repro import obs
 from repro.core import ref_python as R
-from repro.core.distributed import bucket_cap, edge_mesh, gee_distributed
+from repro.core.distributed import edge_mesh, gee_distributed
 from repro.encoder import Embedder, EncoderConfig
 from repro.encoder.plan import effective_weights
 from repro.graph.edges import Graph, make_labels
@@ -69,54 +71,42 @@ out["auto"] = {
                    "devices": len({s.device for s in d[k].addressable_shards})}
                for k in ("rows", "src", "w")},
     "edges_kept": sorted({"u", "v"} & set(d)),
-    "dropped": emb.last_info_["dropped"],
     "spans": [e["name"] for e in obs.trace_events()]}
+
 
 # each device's packed slots are the host packer's for its own share of
 # the padded edges, padded with zero blocks to the fullest device's
-gp = g.pad_to(20008)
-per, T, bpt = 20008 // p, d["rows"].shape[0] // p, d["rows"].shape[1]
-same, host_bpt = [], []
-for i in range(p):
-    e = slice(i * per, (i + 1) * per)
-    host = pack_edges(np.concatenate([gp.u[e], gp.v[e]]),
-                      np.concatenate([gp.v[e], gp.u[e]]),
-                      np.concatenate([gp.w[e], gp.w[e]]), 1004)
-    host_bpt.append(host[0].shape[1])
-    for dev, ref in zip((d["rows"], d["src"], d["w"]), host[:3]):
-        mine = np.asarray(dev)[i * T:(i + 1) * T]
-        b = ref.shape[1]
-        same.append(host[3] == T and b <= bpt and
-                    bool(np.array_equal(mine[:, :b], ref)) and
-                    not mine[:, b:].any())
-out["auto"]["packed_as_host"] = same
-out["auto"]["host_bpt"] = host_bpt
-
-# the class weights read by each donor's label (replicated mode) give
-# the Z that the per-node weights make_w(Y, K) gathered per edge give,
-# bit for bit
-from jax.sharding import PartitionSpec as P
-from repro.core import distributed as D
-from repro.core.gee import edge_contributions, make_w
+def packed_as_host(d, tile_n, edge_block):
+    gp = g.pad_to(20008)
+    per, T, bpt = 20008 // p, d["rows"].shape[0] // p, d["rows"].shape[1]
+    same, host_bpt = [], []
+    for i in range(p):
+        e = slice(i * per, (i + 1) * per)
+        host = pack_edges(np.concatenate([gp.u[e], gp.v[e]]),
+                          np.concatenate([gp.v[e], gp.u[e]]),
+                          np.concatenate([gp.w[e], gp.w[e]]), 1004,
+                          tile_n, edge_block)
+        host_bpt.append(host[0].shape[1])
+        for dev, ref in zip((d["rows"], d["src"], d["w"]), host[:3]):
+            mine = np.asarray(dev)[i * T:(i + 1) * T]
+            b = ref.shape[1]
+            same.append(host[3] == T and b <= bpt and
+                        bool(np.array_equal(mine[:, :b], ref)) and
+                        not mine[:, b:].any())
+    return {"same": same, "host_bpt": host_bpt, "bpt": bpt,
+            "shape": list(d["rows"].shape)}
 
 
-def per_node_weights(u, v, w, Y, Wv):
-    Z = D._scatter_rows(1004, K, *edge_contributions(u, v, w, Y, Wv))
-    return jax.lax.psum(Z, D.AXIS)
-
+out["packing"] = {"256-512": packed_as_host(d, 256, 512)}
+for tile_n, eb in ((64, 128), (32, 64)):
+    e = Embedder(EncoderConfig(K=K, tile_n=tile_n, edge_block=eb),
+                 backend="distributed:reduce_scatter",
+                 plan_cache=None).fit(g, Ys[0])
+    out["packing"][f"{tile_n}-{eb}"] = packed_as_host(e._plan.data,
+                                                      tile_n, eb)
 
 mesh = edge_mesh()
-old = jax.jit(D.shard_map(per_node_weights, mesh,
-                          in_specs=(P(D.AXIS),) * 3 + (P(), P()),
-                          out_specs=P()))
-rep = Embedder(EncoderConfig(K=K), backend="distributed:replicated",
-               plan_cache=None).fit(g, Ys[-1])
-dr = rep._plan.data
-Y_pad = jax.numpy.asarray(np.concatenate([Ys[-1], [-1]]).astype(np.int32))
-Z_old = np.asarray(old(dr["u"], dr["v"], dr["w"], Y_pad, make_w(Y_pad, K)))
-out["auto"]["same_as_make_w"] = bool(np.array_equal(rep.transform(),
-                                                    Z_old[:g.n]))
-Zg, _ = gee_distributed(g, Ys[-1], K=K, mode="reduce_scatter", mesh=mesh)
+Zg = gee_distributed(g, Ys[-1], K=K, mesh=mesh)
 out["auto"]["same_as_gee_distributed"] = bool(
     np.array_equal(emb.transform(), Zg))
 
@@ -134,16 +124,20 @@ out["unpadded"] = {
 # isolated; 501 self-loops; the edge count pads to the mesh
 
 
-def rs_err(g, Y, *, laplacian=False, functional=False):
-    cfg = EncoderConfig(K=K, laplacian=laplacian)
+def rs_err(g, Y, *, K=K, laplacian=False, functional=False, cfg=None):
+    cfg = EncoderConfig(K=K, laplacian=laplacian, **(cfg or {}))
     Zref = R.gee_numpy(g.u, g.v, effective_weights(g, cfg), Y, K, g.n)
     if functional:
-        Z, _ = gee_distributed(g, Y, K=K, mode="reduce_scatter", mesh=mesh,
-                               laplacian=laplacian)
+        Z = gee_distributed(g, Y, K=K, mesh=mesh, laplacian=laplacian)
     else:
         Z = Embedder(cfg, backend="distributed:reduce_scatter",
                      plan_cache=None).fit(g, Y).transform()
-    return float(np.abs(Z - Zref).max())
+    # a class no node carries weighs 0, not 1/0: its column is 0
+    absent = np.setdiff1d(np.arange(K), Y)
+    return {"err": float(np.abs(Z - Zref).max()),
+            "shape": list(Z.shape) == [g.n, K],
+            "finite": bool(np.isfinite(Z).all()),
+            "absent_zero": bool(np.all(Z[:, absent] == 0))}
 
 
 rng = np.random.default_rng(3)
@@ -160,22 +154,27 @@ out["rs_cases"] = {
     "functional": rs_err(weighted, Yc, functional=True),
     "functional_laplacian": rs_err(weighted, Yc, laplacian=True,
                                    functional=True)}
+# the encoder conformance zoo at its small kernel geometry; and a graph
+# with no edges, which pads to one zero-weight edge a device
+for case, (gc, Yz) in _cases().items():
+    out["rs_cases"][case] = rs_err(
+        gc, Yz, K=int(Yz.max()) + 1 if Yz.max() >= 0 else 3, cfg=CFG)
+empty = Graph(np.zeros(0, np.int32), np.zeros(0, np.int32),
+              np.zeros(0, np.float32), 16)
+out["rs_cases"]["empty_graph"] = rs_err(
+    empty, make_labels(16, 3, 0.5, np.random.default_rng(1)), K=3, cfg=CFG)
 
-# every mode through the Embedder: Z, and one embed's counters
-for mode in ("replicated", "reduce_scatter", "a2a", "ring"):
-    name = "distributed:" + mode
-    before = counts(name, mode)
-    e = Embedder(EncoderConfig(K=K), backend=name, plan_cache=None)
-    e.fit(g, Ys[1])
-    out[mode] = {
-        "err": float(np.abs(e.transform() - R.gee_numpy(
-            g.u, g.v, g.w, Ys[1], K, g.n)).max()),
-        "dropped": e.last_info_["dropped"],
-        "cap": bucket_cap(mode, 20008 // p, p,
-                          e._plan.data["capacity_factor"]),
-        "bpt": (e._plan.data["rows"].shape[1] if "rows" in e._plan.data
-                else None),
-        "delta": [a - b for a, b in zip(counts(name, mode), before)]}
+# the backend by name through the Embedder: Z, and one embed's counters
+name = "distributed:reduce_scatter"
+before = counts(name, "reduce_scatter")
+e = Embedder(EncoderConfig(K=K), backend=name, plan_cache=None)
+e.fit(g, Ys[1])
+out["reduce_scatter"] = {
+    "err": float(np.abs(e.transform() - R.gee_numpy(
+        g.u, g.v, g.w, Ys[1], K, g.n)).max()),
+    "bpt": e._plan.data["rows"].shape[1],
+    "delta": [a - b for a, b in zip(counts(name, "reduce_scatter"),
+                                    before)]}
 print("RESULT " + json.dumps(out))
 """
 
@@ -186,14 +185,15 @@ def res():
                XLA_FLAGS=f"--xla_force_host_platform_device_count={P_DEV}",
                PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c",
-                        SCRIPT % {"K": K, "p": P_DEV}],
+                        SCRIPT % {"K": K, "p": P_DEV,
+                                  "tests": os.path.join(REPO, "tests")}],
                        env=env, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     line = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT ")]
     return json.loads(line[-1][len("RESULT "):])
 
 
-S, S_PAD, N_PAD = 20007, 20008, 1004
+S, N_PAD = 20007, 1004
 #: the kernel's tiles over N_PAD rows, and its edge block
 T, EB = 4, 512
 
@@ -210,7 +210,6 @@ def test_auto_resolves_to_reduce_scatter(res):
 def test_fit_and_refits_match_oracle(res, step):
     assert res["auto"]["errs"][step] < 1e-4
     assert res["auto"]["Z_shape"] == [1003, K]
-    assert res["auto"]["dropped"] == 0
 
 
 @pytest.mark.parametrize("arr", ["rows", "src", "w"])
@@ -226,22 +225,37 @@ def test_plan_edges_sharded_a_quarter_per_device(res, arr):
     assert res["auto"]["edges_kept"] == []
 
 
-def test_device_packing_equals_host_packing(res):
+@pytest.mark.parametrize("geometry", ["256-512", "64-128", "32-64"])
+def test_device_packing_equals_host_packing(res, geometry):
     """Each device's (rows, src, w) slots equal `kernels.ops.pack_edges`
     of its own contributions, slot for slot, padded with zero blocks to
-    the fullest device's block count."""
-    assert res["auto"]["packed_as_host"] == [True] * (3 * P_DEV)
-    assert res["auto"]["placed"]["rows"]["shape"][1] == max(
-        res["auto"]["host_bpt"])
+    the fullest device's block count, at (tile_n, edge_block)
+    `geometry`."""
+    m = res["packing"][geometry]
+    tile_n, eb = map(int, geometry.split("-"))
+    assert m["same"] == [True] * (3 * P_DEV)
+    assert m["bpt"] == max(m["host_bpt"])
+    assert m["shape"] == [P_DEV * -(-N_PAD // tile_n), m["bpt"], 1, eb]
+
+
+#: the encoder conformance zoo (`test_encoder._cases`) and a graph with
+#: no edges, held to the conformance suite's tolerance
+ZOO = ["all_unlabeled", "empty_class", "self_loops", "sparsely_labeled",
+       "weighted_directed", "empty_graph"]
 
 
 @pytest.mark.parametrize("case", ["unit", "weighted", "laplacian",
-                                  "functional", "functional_laplacian"])
+                                  "functional", "functional_laplacian"]
+                         + ZOO)
 def test_reduce_scatter_matches_oracle(res, case):
     """Self-loops, isolated nodes, unlabeled donors, n a multiple of
     neither the tile nor the mesh; through the Embedder and through
-    `gee_distributed`, with and without Laplacian scaling."""
-    assert res["rs_cases"][case] < 1e-4
+    `gee_distributed`, with and without Laplacian scaling; the encoder's
+    conformance zoo at its small geometry, where a class no node carries
+    gives a column of 0; and the empty graph, whose Z is all 0."""
+    m = res["rs_cases"][case]
+    assert m["err"] < (1e-5 if case in ZOO else 1e-4)
+    assert m["shape"] and m["finite"] and m["absent_zero"]
 
 
 def test_second_refit_compiles_nothing(res):
@@ -268,11 +282,8 @@ def test_spans(res):
 
 
 def test_class_weights_give_make_w_z(res):
-    """Each donor's weight read from the class weights by its label is
-    the per-node weight `make_w` gathers per edge: the same Z, bit for
-    bit (replicated mode); and reduce_scatter gives the same Z through
-    the Embedder and through `gee_distributed`, bit for bit."""
-    assert res["auto"]["same_as_make_w"]
+    """The class weights scale Z's columns the same way through the
+    Embedder and through `gee_distributed`: the same Z, bit for bit."""
     assert res["auto"]["same_as_gee_distributed"]
 
 
@@ -283,21 +294,13 @@ def test_unpadded_z_stays_row_sharded(res):
     assert u["err"] < 1e-4
 
 
-@pytest.mark.parametrize("mode",
-                         ["replicated", "reduce_scatter", "a2a", "ring"])
+@pytest.mark.parametrize("mode", ["reduce_scatter"])
 def test_mode_counts_by_hand(res, mode):
+    """The backend chosen by name: one embed's p T BPT EB slots, its
+    2 s contributions and (p-1)/p of the (n_pad, K) accumulator sent."""
     m = res[mode]
-    assert m["err"] < 1e-4 and m["dropped"] == 0
-    cap, z = m["cap"], N_PAD * K * 4
-    slots = {"replicated": 2 * S_PAD,
-             "reduce_scatter": P_DEV * T * res["auto"]["placed"]["rows"][
-                 "shape"][1] * EB}.get(mode, P_DEV * P_DEV * cap)
-    assert m["bpt"] == (None if mode != "reduce_scatter" else
-                        max(res["auto"]["host_bpt"]))
-    sent = {"replicated": 2 * (P_DEV - 1) * z // P_DEV,
-            "reduce_scatter": (P_DEV - 1) * z // P_DEV,
-            "a2a": (P_DEV - 1) * cap * 12,
-            "ring": (P_DEV - 1) * z // P_DEV}[mode]
-    assert m["delta"][0] == slots
+    assert m["err"] < 1e-4
+    assert m["bpt"] == max(res["packing"]["256-512"]["host_bpt"])
+    assert m["delta"][0] == P_DEV * T * m["bpt"] * EB
     assert m["delta"][1] + m["delta"][2] == 2 * S
-    assert m["delta"][3] == sent
+    assert m["delta"][3] == (P_DEV - 1) * N_PAD * K * 4 // P_DEV

@@ -145,7 +145,7 @@ def test_kernel_name_survives_enclosing_jit(one_chip, kernel):
 
 def test_reduce_scatter_embed_compiles_on_four_chips(topo):
     """`distributed:reduce_scatter`'s embed (`core.distributed
-    ._packed_program`) on a 2x2 mesh: each chip's (T, 29, 1, EB) packed
+    ._program`) on a 2x2 mesh: each chip's (T, 29, 1, EB) packed
     slots (n_pad = 3.0M; the fullest tile of `er-4chip`'s graph fills
     29 blocks) through the kernel, one cross-chip reduction that runs
     as an all-reduce of its own, within a chip's 16 GB."""
@@ -157,7 +157,7 @@ def test_reduce_scatter_embed_compiles_on_four_chips(topo):
     edges, rep = NamedSharding(mesh, P(D.AXIS)), NamedSharding(mesh, P())
     slots = (4 * -(-n // TILE_N), 29, 1, EB)
     compiled = _compiles_with_kernel(
-        D._packed_program(K, n, mesh, TILE_N, False),
+        D._program(K, n, mesh, TILE_N, False),
         "gee_scatter_pallas",
         _spec(slots, jnp.int32, edges), _spec(slots, jnp.int32, edges),
         _spec(slots, jnp.float32, edges), _spec((n,), jnp.int32, rep),
