@@ -47,6 +47,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.gee import make_w
+from repro.encoder import class_fill
 from repro.encoder.config import EncoderConfig
 from repro.encoder.plan import Plan, effective_weights, owned_contributions
 from repro.graph.edges import Graph
@@ -260,16 +261,21 @@ class PallasBackend(Backend):
 
     The plan packs (tile-local row, source node, weight) — all
     label-free — so refits resolve each slot's class on device from the
-    current Y, with one gather over the packed slots, and skip the
-    O(s log s) host sort entirely.  The kernel scatters the packed
-    weight under that class; an unlabeled donor's class -1 matches no
-    column of the kernel's one-hot, so it adds nothing.  The projection
+    current Y and skip the O(s log s) host sort entirely.  The kernel
+    scatters the packed weight under that class; an unlabeled donor's
+    class -1 matches no column of the kernel's one-hot, so it adds
+    nothing.  The classes come by one of two routes, chosen per embed
+    from the host labels (`class_fill`): where labeled donors own few
+    slots, their classes are written into a buffer of -1 through the
+    plan's donor index (route "labeled"); else Y is gathered at every
+    slot (route "gather", also taken while tracing).  The projection
     weight 1/n_k depends only on the class, which is the contribution's
     column of Z, so it is applied once per column of the (n_local, K)
     result (`class_w`), not per slot.  Padded slots
-    carry w = 0 and are no-ops for any labeling.  The packed buffers
-    are the host half: a persistent-cache hit skips the sort in a fresh
-    process too.
+    carry w = 0 and donor n, which is no node, and are no-ops for any
+    labeling.  The packed buffers are the host half: a persistent-cache
+    hit skips the sort in a fresh process too; the donor index is built
+    from them on the device in every process (`plan_finalize`).
 
     Under a row partition the contributions bucketed by owned
     destination (`plan.owned_contributions`, destinations remapped to
@@ -290,7 +296,8 @@ class PallasBackend(Backend):
     supports_row_partition = True
     #: v2: partitioned plans pack over local rows [0, hi - lo)
     #: v3: packed blocks are (T, BPT, 1, EB), the layout Mosaic tiles
-    plan_version = 3
+    #: v4: padded slots hold donor n, so the donor index skips them
+    plan_version = 4
 
     def plan_host(self, graph, config, w_eff):
         from repro.kernels.ops import _round_up, pack_edges
@@ -305,7 +312,8 @@ class PallasBackend(Backend):
             w2 = np.concatenate([w_eff, w_eff])
             n_rows = graph.n
         rows, srcb, wb, T = pack_edges(dst, src, w2, n_rows,
-                                       config.tile_n, config.edge_block)
+                                       config.tile_n, config.edge_block,
+                                       pad_cls=graph.n)
         return {"rows": rows, "src": srcb, "w_packed": wb, "T": T,
                 "kdim": _round_up(config.K, 8)}
 
@@ -314,10 +322,12 @@ class PallasBackend(Backend):
                                                resolve_interpret)
         h = p.host
         interp = resolve_interpret(p.config.interpret)
-        p.data = {"rows": jnp.asarray(h["rows"]),
-                  "src": jnp.asarray(h["src"]),
+        src = jnp.asarray(h["src"])
+        p.data = {"rows": jnp.asarray(h["rows"]), "src": src,
                   "w": jnp.asarray(np.asarray(h["w_packed"], np.float32)),
                   "T": int(h["T"]), "kdim": int(h["kdim"]),
+                  "donors": class_fill.DonorIndex.build(src,
+                                                        p.donor_counts()),
                   "interpret": interp}
         if obs.enabled():
             obs.gauge("repro_kernels_pallas_interpret_mode",
@@ -328,13 +338,33 @@ class PallasBackend(Backend):
         from repro.kernels.gee_scatter import gee_scatter_pallas
         d, cfg = plan.data, plan.config
         with obs.span("encoder.gather"):
-            Ys = Yj[d["src"]]
+            cls, route = self._classes(d, Yj)
         with obs.span("encoder.scatter"):
-            Z = gee_scatter_pallas(d["rows"], Ys, d["w"], num_tiles=d["T"],
+            Z = gee_scatter_pallas(d["rows"], cls, d["w"], num_tiles=d["T"],
                                    tile_n=cfg.tile_n, kdim=d["kdim"],
                                    interpret=d["interpret"])
             Z = _scale_columns(Z, class_w, n=plan.n_local)
-        return Z, {"interpret": d["interpret"]}
+        return Z, {"interpret": d["interpret"], "route": route}
+
+    def _classes(self, d, Yj):
+        """(each packed slot's class, the route that gave it): the
+        labeled donors' classes written through the donor index while
+        those writes cost less than a gather at every slot, else the
+        gather.  Counts the route, and the fill's writes, per embed."""
+        idx = d["donors"]
+        if not isinstance(Yj, jax.core.Tracer):
+            Y = np.asarray(Yj)
+            sel = idx.labeled_rows(Y)
+            writes = sel.size * idx.width
+            if writes * class_fill.FILL_WRITE_COST < idx.slots:
+                obs.counter("repro_kernel_class_fill_total",
+                            backend=self.name, route="labeled")
+                obs.counter("repro_kernel_class_writes_total", writes,
+                            backend=self.name)
+                return idx.fill(Y, sel, d["src"].shape), "labeled"
+        obs.counter("repro_kernel_class_fill_total", backend=self.name,
+                    route="gather")
+        return Yj[d["src"]], "gather"
 
     def slots(self, plan):
         return int(plan.data["rows"].size)         # T * bpt * EB

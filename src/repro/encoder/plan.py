@@ -112,9 +112,10 @@ class Plan:
     def donor_counts(self) -> np.ndarray:
         """How many contributions take their class and value from each
         node's label: its degree, or under a row partition its edges
-        into the owned rows.  Label free, so built once per plan, by
-        the first embed that counts its work (obs on only); an embed's
-        labeled contributions are then ``donor_counts() @ (Y >= 0)``."""
+        into the owned rows.  Label free, so built once per plan: by a
+        pallas plan's finalize (its donor index), else by the first
+        embed that counts its work (obs on only); an embed's labeled
+        contributions are then ``donor_counts() @ (Y >= 0)``."""
         if self._donors is None:
             n, src = self.n, self.host.get("o_src")   # owned plans' donors
             if src is not None:

@@ -25,11 +25,12 @@ def _round_up(x: int, m: int) -> int:
 
 
 def pack_edges(dst, cls, val, n: int, tile_n: int = TILE_N,
-               edge_block: int = EDGE_BLOCK):
+               edge_block: int = EDGE_BLOCK, pad_cls: int = 0):
     """Sort contributions by destination tile and pack into uniform
     (T, BPT, 1, EB) blocks (the unit axis is the kernels' lane-dense
     block row, see `gee_scatter`).  Host-side numpy (static output
-    shapes depend on the max bucket size).  Padded slots: val = 0."""
+    shapes depend on the max bucket size).  Padded slots: val = 0,
+    cls = pad_cls."""
     dst = np.asarray(dst)
     cls = np.asarray(cls)
     val = np.asarray(val)
@@ -46,7 +47,7 @@ def pack_edges(dst, cls, val, n: int, tile_n: int = TILE_N,
     slot = tile_s * (bpt * edge_block) + pos
 
     rows_buf = np.zeros(slots, np.int32)
-    cls_buf = np.zeros(slots, np.int32)
+    cls_buf = np.full(slots, pad_cls, np.int32)
     val_buf = np.zeros(slots, np.float32)
     rows_buf[slot] = dst_s - tile_s * tile_n
     cls_buf[slot] = cls_s

@@ -638,14 +638,38 @@ class TestWorkCounters:
 
     @pytest.mark.parametrize("backend", ["pallas", "streaming"])
     def test_off_counts_nothing_and_builds_no_donor_counts(self, backend):
-        """With obs off the plan never pays for the donor counts."""
+        """With obs off nothing is counted, and no plan pays for the
+        donor counts but the pallas plan, whose donor index is laid out
+        from them (`class_fill.DonorIndex`)."""
         obs.configure(enabled=False)
         g = erdos_renyi(300, 2000, seed=8)
         Y = make_labels(300, 4, 0.3, np.random.default_rng(8))
         emb = Embedder(EncoderConfig(K=4, **CFG), backend=backend)
         emb.fit(g, Y).refit(Y)
-        assert emb._plan._donors is None
+        assert (emb._plan._donors is None) == (backend != "pallas")
         assert not obs.registry().series_names()
+
+    def test_class_fill_route_and_writes(self):
+        """One `repro_kernel_class_fill_total` per pallas embed, by
+        route: a fit at 10% labeled fills, each refinement round (every
+        node labeled) gathers; the fill counts its writes, unused lanes
+        of its rows included."""
+        g = erdos_renyi(2000, 20000, seed=10)
+        Y = make_labels(2000, 4, 0.1, np.random.default_rng(10))
+        emb = Embedder(EncoderConfig(K=4, refine_iters=2, **CFG),
+                       backend="pallas", plan_cache=None).fit(g, Y)
+        r, idx = obs.registry(), emb._plan.data["donors"]
+
+        def counts():
+            return [r.counter_value("repro_kernel_class_fill_total",
+                                    backend="pallas", route=route)
+                    for route in ("labeled", "gather")] + [
+                r.counter_value("repro_kernel_class_writes_total",
+                                backend="pallas")]
+        writes = int((Y[idx.row_donor] >= 0).sum()) * idx.width
+        assert counts() == [1, 0, writes]
+        emb.refine()        # refine_iters rounds and the final embed
+        assert counts() == [1, 3, writes]
 
     def test_rejected_labels_not_timed(self):
         g = erdos_renyi(300, 2000, seed=9)
@@ -720,3 +744,166 @@ class TestClassAxisWeights:
                    if e.primitive.name == "gather"
                    and e.invars[1].aval.size == slots]
         assert len(gathers) == 1
+
+
+def _labels_at(n, K, share, seed):
+    """share of n nodes labeled: none at 0, every node at 1."""
+    rng = np.random.default_rng(seed)
+    if share == 0:
+        return np.full(n, -1, np.int32)
+    if share == 1:
+        return rng.integers(0, K, n).astype(np.int32)
+    return make_labels(n, K, share, rng)
+
+
+class TestClassFill:
+    """The pallas backend writes the labeled donors' classes through the
+    plan's donor index (route "labeled") or gathers Y at every packed
+    slot (route "gather"): the same class on every real slot, -1 on the
+    padded ones from the fill, and the same Z bit for bit."""
+
+    @staticmethod
+    def _fit(monkeypatch, route, g, Y, K=5, **cfg):
+        from repro.encoder import class_fill
+        # 0: every write is free, so the fill is always cheaper; inf:
+        # never (0 writes cost nan, which is not below the slots either)
+        monkeypatch.setattr(class_fill, "FILL_WRITE_COST",
+                            0.0 if route == "labeled" else float("inf"))
+        emb = Embedder(EncoderConfig(K=K, **CFG, **cfg), backend="pallas",
+                       plan_cache=None).fit(g, Y)
+        assert emb.last_info_["route"] == route
+        return emb
+
+    @staticmethod
+    def _filled(plan, Y):
+        """The fill's classes, and what they must be: Y at each real
+        slot's donor, -1 where the slot is padding (donor n)."""
+        d = plan.data
+        src = np.asarray(d["src"])
+        cls = d["donors"].fill(Y, d["donors"].labeled_rows(Y), src.shape)
+        want = np.where(src < plan.n, Y[np.minimum(src, plan.n - 1)], -1)
+        return np.asarray(cls), want
+
+    @pytest.mark.parametrize("rp", [None, (700, 1500)],
+                             ids=["whole", "rows"])
+    @pytest.mark.parametrize("share", [0, 0.001, 0.1, 0.5, 1])
+    def test_routes_agree(self, monkeypatch, share, rp):
+        g = erdos_renyi(2000, 9000, seed=11, weighted=True)
+        Y = _labels_at(g.n, 5, share, 11)
+        kw = {} if rp is None else {"row_partition": rp}
+        fill = self._fit(monkeypatch, "labeled", g, Y, **kw)
+        gather = self._fit(monkeypatch, "gather", g, Y, **kw)
+        cls, want = self._filled(fill._plan, Y)
+        np.testing.assert_array_equal(cls, want)
+        assert np.array_equal(np.asarray(fill.Z_), np.asarray(gather.Z_))
+        if rp is None:
+            np.testing.assert_allclose(np.asarray(fill.Z_),
+                                       _oracle(g, Y, 5), atol=1e-5)
+
+    def test_padding_never_joins_donor_zero(self, monkeypatch):
+        """pack_edges pads with donor n, so node 0, labeled, owns only
+        its own slots: its rows, the fill's writes and the -1 on every
+        padded slot say so."""
+        obs.configure(enabled=True)
+        obs.reset()
+        try:
+            g = erdos_renyi(300, 1500, seed=12, weighted=True)
+            Y = make_labels(300, 5, 0.2, np.random.default_rng(12))
+            Y[0] = 3
+            emb = self._fit(monkeypatch, "labeled", g, Y)
+            d = emb._plan.data
+            idx, src = d["donors"], np.asarray(d["src"])
+            assert (src == g.n).sum() > 0             # padded slots exist
+            deg0 = int((g.u == 0).sum() + (g.v == 0).sum())
+            assert (idx.row_donor == 0).sum() == -(-deg0 // idx.width)
+            cls, want = self._filled(emb._plan, Y)
+            np.testing.assert_array_equal(cls, want)
+            assert (cls[src == g.n] == -1).all()
+            rows = (Y[idx.row_donor] >= 0).sum()
+            assert obs.registry().counter_value(
+                "repro_kernel_class_writes_total",
+                backend="pallas") == rows * idx.width
+        finally:
+            obs.reset()
+
+    def test_donor_wider_than_a_row(self, monkeypatch):
+        """A hub whose slots span several rows keeps every one."""
+        rng = np.random.default_rng(13)
+        base = erdos_renyi(400, 1200, seed=13, weighted=True)
+        hub = np.zeros(700, np.int32)
+        others = rng.integers(1, 400, 700).astype(np.int32)
+        g = Graph(np.concatenate([base.u, hub]),
+                  np.concatenate([base.v, others]),
+                  np.concatenate([base.w, rng.random(700, np.float32)]),
+                  400)
+        Y = make_labels(400, 5, 0.3, rng)
+        Y[0] = 1
+        fill = self._fit(monkeypatch, "labeled", g, Y)
+        idx = fill._plan.data["donors"]
+        assert (idx.row_donor == 0).sum() > 1
+        cls, want = self._filled(fill._plan, Y)
+        np.testing.assert_array_equal(cls, want)
+        gather = self._fit(monkeypatch, "gather", g, Y)
+        assert np.array_equal(np.asarray(fill.Z_), np.asarray(gather.Z_))
+        np.testing.assert_allclose(np.asarray(fill.Z_), _oracle(g, Y, 5),
+                                   atol=1e-5)
+
+    def test_churned_label_sets_share_one_program(self):
+        """Four label sets at about 11% labeled (1% of nodes revealed, 1%
+        of the known flipped), refit back to back after a warm fit on a
+        fifth: every refit fills, and none compiles."""
+        g = erdos_renyi(3000, 40000, seed=14)
+        rng = np.random.default_rng(14)
+        truth = rng.integers(0, 5, g.n).astype(np.int32)
+        Y0 = make_labels(g.n, 5, 0.1, rng, true_labels=truth)
+
+        def churn(Y):
+            Y2 = Y.copy()
+            reveal = rng.choice(g.n, g.n // 100, replace=False)
+            Y2[reveal] = truth[reveal]
+            known = np.flatnonzero(Y2 >= 0)
+            flip = rng.choice(known, known.size // 100, replace=False)
+            Y2[flip] = rng.integers(0, 5, flip.size)
+            return Y2
+
+        sets = [churn(Y0) for _ in range(5)]
+        obs.configure(enabled=True)
+        obs.reset()
+        try:
+            emb = Embedder(EncoderConfig(K=5, **CFG), backend="pallas",
+                           plan_cache=None).fit(g, sets[0])
+            r = obs.registry()
+            c0 = r.counter_value("repro_jax_compiles_total")
+            for Y in sets[1:]:
+                emb.refit(Y)
+                assert emb.last_info_["route"] == "labeled"
+                np.testing.assert_allclose(np.asarray(emb.Z_),
+                                           _oracle(g, Y, 5), atol=1e-5)
+            assert r.counter_value("repro_jax_compiles_total") == c0
+            assert r.counter_value("repro_kernel_class_fill_total",
+                                   backend="pallas", route="labeled") == 5
+        finally:
+            obs.reset()
+
+    def test_row_width_follows_degrees(self):
+        """Binomial degrees of mean 37.5 (a uniform graph at 2s/n = 37.5)
+        take rows of 48: most donors fit one row; a hub adds rows, not
+        width; no donor at all gives the narrowest row."""
+        from repro.encoder.class_fill import row_width
+        d = np.random.default_rng(15).binomial(60_000, 37.5 / 60_000,
+                                               20_000)
+        assert row_width(d) == 48
+        assert row_width(np.append(d, 100_000)) == 48
+        assert row_width(np.zeros(10, np.int64)) == 8
+
+    def test_bucket_shares_shapes_across_graphs(self):
+        """The index's rows and the fill's capacity round up by at most a
+        sixteenth: er-batch's row counts over five graphs (1,664,679 to
+        1,665,103) take one table shape, so one compiled program."""
+        from repro.encoder.class_fill import _bucket
+        xs = np.arange(1, 5000)
+        b = np.array([_bucket(x) for x in xs])
+        assert (b >= xs).all() and (b * 16 <= xs * 17).all()
+        assert (np.diff(b) >= 0).all()
+        assert {_bucket(r) for r in (1_664_679, 1_664_700, 1_665_103)} \
+            == {1_703_936}

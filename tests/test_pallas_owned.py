@@ -130,6 +130,15 @@ class TestOwnedRowsPallas:
         assert b.plan_stats == {"built": 0, "hits": 0,
                                 "disk_hits": 1, "disk_stores": 0}
         assert np.array_equal(np.asarray(a.Z_), np.asarray(b.Z_))
+        # the packed donors round-trip with their padding (donor n), and
+        # the donor index laid out from them is the same
+        src = b._plan.host["src"]
+        assert np.array_equal(src, a._plan.host["src"])
+        assert (src == g.n).any()
+        ia, ib = a._plan.data["donors"], b._plan.data["donors"]
+        assert np.array_equal(ia.row_donor, ib.row_donor)
+        assert np.array_equal(np.asarray(ia.slot_rows),
+                              np.asarray(ib.slot_rows))
         c = Embedder(EncoderConfig(K=5, tile_n=64, edge_block=128,
                                    row_partition=(110, 220)),
                      backend="pallas", plan_cache=tmp_path)
